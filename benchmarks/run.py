@@ -163,6 +163,8 @@ def main(argv=None) -> None:
                     help="write summary + raw rows to this JSON file")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     sections = _sections()
     if args.only:
         names = [name for name, _, _ in sections]

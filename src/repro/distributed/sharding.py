@@ -22,17 +22,10 @@ Rules = Dict[str, Any]  # logical name -> mesh axis | tuple | None
 
 
 def abstract_mesh(shape: Sequence[int], axis_names: Sequence[str]):
-    """Version-portable ``jax.sharding.AbstractMesh`` constructor.
-
-    jax changed the signature from ``AbstractMesh(shape, axis_names)`` to
-    ``AbstractMesh(shape_tuple)`` with ``shape_tuple`` an (name, size)
-    tuple-of-tuples; sharding rules only need ``mesh.shape``, so accept
-    either installed API."""
+    """A device-free ``AbstractMesh`` (sharding rules only read
+    ``mesh.shape``)."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axis_names, shape)))
-    except (TypeError, ValueError):
-        return AbstractMesh(tuple(shape), tuple(axis_names))
+    return AbstractMesh(tuple(shape), tuple(axis_names))
 
 RULESETS: Dict[str, Rules] = {
     # paper-faithful baseline: TP(model) x FSDP(data), experts TP-sliced

@@ -39,14 +39,15 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, ig_ref, fg_ref, h_ref,
     ig = ig_ref[0].astype(jnp.float32)                   # (T, 1)
     lf = jax.nn.log_sigmoid(fg_ref[0].astype(jnp.float32))
 
-    bc = jnp.cumsum(lf, axis=0)                          # (T, 1)
+    causal = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
+        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum over time (Mosaic has no cumsum): masked row sum
+    bc = jnp.sum(jnp.where(causal, lf.T, 0.0), axis=1, keepdims=True)  # (T, 1)
     bt = bc[chunk - 1]                                   # (1,)
     m_prev = m_ref[0, 0]
 
     # intra-chunk pair log-weights a[t, s] = bc_t - bc_s + ig_s (causal)
     a = bc - bc.T + ig.T                                 # (T, T)
-    causal = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     a = jnp.where(causal, a, NEG_INF)
     m_intra = jnp.max(a, axis=1, keepdims=True)          # (T, 1)
     m_inter = bc + m_prev                                # (T, 1)

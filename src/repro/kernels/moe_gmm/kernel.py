@@ -6,8 +6,10 @@ the grid's innermost (sequential) dimension walks f-blocks, accumulating the
 down-projection into a VMEM scratch accumulator — the hidden activation
 exists only as one (block_c x block_f) VMEM tile at a time.
 
-VMEM budget per step (mixtral-8x7b, d=4096, block_c=128, block_f=512, bf16):
-x 1 MiB + w1/w3 4 MiB each + w2 4 MiB + acc(f32) 2 MiB ~= 15 MiB << 128 MiB.
+VMEM per step (mixtral-8x7b, d=4096, block_c=128, bf16): every in/out tile
+is double-buffered, so block_f=512 needs x+y 2 MiB + w1/w3/w2 12 MiB, twice,
+plus acc(f32) 2 MiB = 30 MiB, which the compiler refuses against v5e's
+16 MiB scoped limit; ops._pick_blocks shrinks block_f to 128 (12 MiB).
 Tiles are MXU-aligned (128-multiples in c/f/d).
 """
 from __future__ import annotations

@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 RGLRU_C = 8.0
+SUB = 8          # time steps per aligned load/store (one f32 sublane tile)
 
 
 def _rglru_kernel(x_ref, lam_ref, ga_ref, gx_ref, y_ref, h_ref, hout_ref,
@@ -30,21 +31,27 @@ def _rglru_kernel(x_ref, lam_ref, ga_ref, gx_ref, y_ref, h_ref, hout_ref,
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[...].astype(jnp.float32)                  # (bb, bs, bd)
-    lam = lam_ref[...].astype(jnp.float32)              # (1, bd)
-    log_a = -RGLRU_C * jax.nn.softplus(lam) * jax.nn.sigmoid(
-        ga_ref[...].astype(jnp.float32))                # (bb, bs, bd)
-    a = jnp.exp(log_a)
-    beta = jnp.sqrt(-jnp.expm1(2.0 * log_a))
-    b = beta * jax.nn.sigmoid(gx_ref[...].astype(jnp.float32)) * x
+    c = -RGLRU_C * jax.nn.softplus(
+        lam_ref[...].astype(jnp.float32))               # (1, bd)
 
-    def step(t, h):
-        h = a[:, t, :] * h + b[:, t, :]
-        pl.store(y_ref, (slice(None), pl.dslice(t, 1), slice(None)),
-                 h[:, None, :].astype(y_ref.dtype))
+    def step(g, h):
+        # one sublane-aligned group of time steps: (bb, SUB, bd)
+        at = pl.ds(pl.multiple_of(g * SUB, SUB), SUB)
+        x = x_ref[:, at, :].astype(jnp.float32)
+        log_a = c * jax.nn.sigmoid(ga_ref[:, at, :].astype(jnp.float32))
+        a = jnp.exp(log_a)
+        # sqrt(1 - a^2) without cancellation near a = 1: Mosaic has no
+        # expm1, and expm1(2y) = tanh(y) * (exp(2y) + 1)
+        beta = jnp.sqrt(-jnp.tanh(log_a) * (1.0 + a * a))
+        b = beta * jax.nn.sigmoid(gx_ref[:, at, :].astype(jnp.float32)) * x
+        hs = []
+        for t in range(SUB):
+            h = a[:, t, :] * h + b[:, t, :]
+            hs.append(h)
+        y_ref[:, at, :] = jnp.stack(hs, axis=1).astype(y_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, block_s, step, h_ref[...])
+    h = jax.lax.fori_loop(0, block_s // SUB, step, h_ref[...])
     h_ref[...] = h
 
     @pl.when(it == pl.num_programs(2) - 1)

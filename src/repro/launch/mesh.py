@@ -13,6 +13,13 @@ model (TP) inside a pod.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # the sharding rules steer the partitioner with with_sharding_constraint,
+    # which needs Auto axes; jax.make_mesh defaults to Explicit ones
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, dp: int = 16,
@@ -23,14 +30,14 @@ def make_production_mesh(*, multi_pod: bool = False, dp: int = 16,
         raise ValueError(f"intra-pod mesh must have 256 chips, got {dp}x{tp}")
     shape = (2, dp, tp) if multi_pod else (dp, tp)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(model_axis: int = 1):
     """Whatever this host has — used by tests and the CPU examples."""
     n = jax.device_count()
     model_axis = max(1, min(model_axis, n))
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return _auto_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def mesh_chips(mesh) -> int:

@@ -13,6 +13,7 @@ from __future__ import annotations
 import argparse
 import time
 from dataclasses import dataclass, field
+from functools import partial
 from typing import List, Optional
 
 import jax
@@ -34,9 +35,20 @@ class Request:
     t_done: Optional[float] = None
 
 
+@partial(jax.jit, static_argnums=1)
+def _init_params(key, cfg):
+    return R.init_params(key, cfg)[0]
+
+
+def make_params(cfg, seed: int = 0):
+    """The model's random weights from ``seed``, built in one compiled
+    program so the device holds no full-size temporary per weight."""
+    return _init_params(jax.random.key(seed), cfg)
+
+
 def serve(cfg, requests: List[Request], *, slots: int = 4,
           ctx_len: int = 512, seed: int = 0, greedy: bool = True):
-    params, _ = R.init_params(jax.random.key(seed), cfg)
+    params = make_params(cfg, seed)
     prefill = jax.jit(make_prefill_step(cfg, cache_len=ctx_len))
     decode = jax.jit(make_serve_step(cfg, greedy=greedy))
 
@@ -74,6 +86,8 @@ def serve(cfg, requests: List[Request], *, slots: int = 4,
                         active[i] = None
                     else:
                         r.generated.append(int(nxt[i, 0]))
+            # free this batch's KV cache before the next prefill allocates
+            del logits, cache
     return done
 
 

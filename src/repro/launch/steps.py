@@ -168,21 +168,10 @@ def make_train_step_dp_compressed(cfg: ArchConfig, mesh,
     def specs_like(tree, spec):
         return jax.tree.map(lambda _: spec, tree)
 
-    def _partial_auto_shard_map(fn, in_specs, out_specs):
-        # jax >= 0.7 spells partial-auto as axis_names=/check_vma=; older
-        # versions use the experimental module with auto=/check_rep=
-        if hasattr(jax, "shard_map"):
-            return jax.shard_map(fn, mesh=mesh, axis_names={"pod"},
-                                 in_specs=in_specs, out_specs=out_specs,
-                                 check_vma=False)
-        from jax.experimental.shard_map import shard_map
-        auto = frozenset(mesh.axis_names) - {"pod"}
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False, auto=auto)
-
     def train_step(params, opt_state, errors, batch):
-        f = _partial_auto_shard_map(
-            body,
+        # partial-auto: manual over "pod" only
+        f = jax.shard_map(
+            body, mesh=mesh, axis_names={"pod"}, check_vma=False,
             in_specs=(specs_like(params, P()), specs_like(opt_state, P()),
                       specs_like(errors, P("pod")),
                       {k: batch_spec[k] for k in batch}),

@@ -8,9 +8,6 @@ import pytest
 pytestmark = pytest.mark.slow        # subprocess compile: CI slow tier
 
 
-@pytest.mark.xfail(reason="partial-auto shard_map over the pod axis hits an "
-                          "XLA IsManualSubgroup crash on the pinned jax "
-                          "0.4.37; pre-existing seed breakage", strict=False)
 def test_compressed_step_matches_plain(tmp_path):
     """Runs in a subprocess (needs 8 fake devices before jax init)."""
     code = """

@@ -10,9 +10,6 @@ import pytest
 pytestmark = pytest.mark.slow        # subprocess compile: CI slow tier
 
 
-@pytest.mark.xfail(reason="xlstm decode cell fails SPMD partitioning on the "
-                          "pinned jax 0.4.37 (involuntary remat check in "
-                          "XLA); pre-existing seed breakage", strict=False)
 def test_dryrun_cli_one_cell(tmp_path):
     root = os.path.join(os.path.dirname(__file__), "..")
     env = dict(os.environ, PYTHONPATH="src")

@@ -64,7 +64,9 @@ def test_moe_gmm_sweep(E, C, d, f, act, dtype):
 
 # --------------------------------------------------------------------- rglru
 @pytest.mark.parametrize("B,S,D", [(2, 256, 256), (1, 128, 128),
-                                   (4, 64, 384), (2, 512, 128)])
+                                   (4, 64, 384), (2, 512, 128),
+                                   (2, 200, 128), (2, 64, 640),
+                                   (12, 64, 128)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_rglru_sweep(B, S, D, dtype):
     from repro.kernels.rglru_scan import ops, ref
