@@ -25,6 +25,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+from repro.core import tracing
+
 
 class ConnectorCopyKind(enum.Enum):
     LOCAL_TO_REMOTE = "localToRemote"
@@ -100,15 +102,19 @@ class ObjectStore:
         """Store a payload under ``path``; returns its content digest.
         A duplicate put (payload already in the CAS) only adds an index
         entry — the bytes are not held twice."""
-        digest = content_digest(payload)
-        with self._lock:
-            if digest in self._cas:
-                self.dedup_puts += 1
-                self.dedup_bytes += len(payload)
-            else:
-                self._cas[digest] = payload
-            self._bind(path, digest)
-            self.bytes_in += len(payload)
+        with tracing.span("sf:cas_put") as sp:
+            digest = content_digest(payload)
+            with self._lock:
+                dedup = digest in self._cas
+                if dedup:
+                    self.dedup_puts += 1
+                    self.dedup_bytes += len(payload)
+                else:
+                    self._cas[digest] = payload
+                self._bind(path, digest)
+                self.bytes_in += len(payload)
+            sp.set("bytes", len(payload))
+            sp.set("dedup", dedup)
         return digest
 
     def get(self, path: str) -> bytes:
@@ -177,11 +183,16 @@ class ObjectStore:
 
 
 def serialize(value: Any) -> bytes:
-    return pickle.dumps(value, protocol=pickle.HIGHEST_PROTOCOL)
+    with tracing.span("sf:encode") as sp:
+        payload = pickle.dumps(value, protocol=pickle.HIGHEST_PROTOCOL)
+        sp.set("bytes", len(payload))
+    return payload
 
 
 def deserialize(payload: bytes) -> Any:
-    return pickle.loads(payload)
+    with tracing.span("sf:decode") as sp:
+        sp.set("bytes", len(payload))
+        return pickle.loads(payload)
 
 
 class Connector(abc.ABC):

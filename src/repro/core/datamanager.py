@@ -46,6 +46,7 @@ from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple, Union
 
+from repro.core import tracing
 from repro.core.connector import (Connector, ConnectorCopyKind, ObjectStore,
                                   deserialize, serialize)
 from repro.core.topology import (MANAGEMENT, Route, TopologyGraph,
@@ -423,7 +424,15 @@ class DataManager:
         is the single implementation both entry points share; prefer the
         async-first :meth:`transfer` on hot paths (it adds in-flight
         deduplication per destination)."""
-        token = _token_key(ref)
+        with tracing.span("sf:transfer") as sp:
+            rec = self._transfer_sync(_token_key(ref), dst_model,
+                                      dst_resource)
+            sp.set("kind", rec.kind)
+            sp.set("bytes", rec.bytes)
+        return rec
+
+    def _transfer_sync(self, token: str, dst_model: str,
+                       dst_resource: str) -> TransferRecord:
         t0 = time.time()
         dst_conn = self.deployment_manager.get_connector(dst_model)
         if dst_conn is None:
@@ -477,7 +486,7 @@ class DataManager:
             # the source site died between planning and execution: re-plan
             # (liveness filtering drops its replicas on the next pass, so
             # this converges to another source or a clean KeyError)
-            return self.transfer_sync(token, dst_model, dst_resource)
+            return self._transfer_sync(token, dst_model, dst_resource)
         if plan.kind == "mgmt-push":
             # one hop: the management node already holds the payload
             n = dst_conn.copy(token, self._rkey(token),
@@ -589,8 +598,8 @@ class DataManager:
             if fut is not None:
                 self.dedup_hits += 1
                 return fut
-            fut = self._pool().submit(self.transfer_sync, token,
-                                      dst_model, dst_resource)
+            fut = self._pool().submit(tracing.bound(self.transfer_sync),
+                                      token, dst_model, dst_resource)
             self._inflight[key] = fut
 
         def _clear(f, key=key):

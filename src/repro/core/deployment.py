@@ -17,6 +17,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Protocol, runtime_checkable
 
+from repro.core import tracing
 from repro.core.connector import Connector
 from repro.core.connectors import get_external_site, make_connector
 
@@ -136,10 +137,13 @@ class DeploymentManager:
                     self._journal(model_name, "attach")
                 else:
                     conn = make_connector(spec.name, spec.type, spec.config)
-                    t0 = time.time()
-                    conn.deploy()
-                    self.timeline.append((model_name, "deploy", t0,
-                                          time.time()))
+                    with tracing.span("sf:deploy") as sp:
+                        sp.set("model", model_name)
+                        sp.set("event", "deploy")
+                        t0 = time.time()
+                        conn.deploy()
+                        self.timeline.append((model_name, "deploy", t0,
+                                              time.time()))
                     self._journal(model_name, "deploy")
                 dep = _Deployment(conn, time.time())
                 self.deployments_map[model_name] = dep
@@ -223,15 +227,18 @@ class DeploymentManager:
 
     def _teardown(self, model_name: str, dep: _Deployment):
         """Physical teardown of a deployment already popped from the map."""
-        t0 = time.time()
-        with self._lock:
-            spec = self._specs.get(model_name)
-        if spec is None or not spec.external:
-            dep.connector.undeploy()
-            self._journal(model_name, "undeploy")
-        else:
-            self._journal(model_name, "detach")
-        self.timeline.append((model_name, "undeploy", t0, time.time()))
+        with tracing.span("sf:deploy") as sp:
+            sp.set("model", model_name)
+            sp.set("event", "undeploy")
+            t0 = time.time()
+            with self._lock:
+                spec = self._specs.get(model_name)
+            if spec is None or not spec.external:
+                dep.connector.undeploy()
+                self._journal(model_name, "undeploy")
+            else:
+                self._journal(model_name, "detach")
+            self.timeline.append((model_name, "undeploy", t0, time.time()))
 
     def undeploy_all(self):
         """End-of-workflow / on-exception cleanup (paper's conservative

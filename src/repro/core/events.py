@@ -144,12 +144,19 @@ class EventSink:
         self._lock = threading.Lock()
 
     def emit(self, ev: WorkflowEvent):
+        # seq is drawn and the event enqueued in one critical section, so
+        # the consumer sees events in seq order whatever thread emits them
+        # (a producer blocked on a full queue holds the lock; the others
+        # queue behind it, and ``abandon`` frees them all)
         with self._lock:
             ev.seq = next(self._seq)
-        ev.t = time.time()
+            ev.t = time.time()
+            self._put(ev)
+
+    def _put(self, item):
         while not self._abandoned.is_set():
             try:
-                self._q.put(ev, timeout=0.05)
+                self._q.put(item, timeout=0.05)
                 return
             except queue.Full:
                 continue
@@ -159,12 +166,7 @@ class EventSink:
             if self._closed:
                 return
             self._closed = True
-        while not self._abandoned.is_set():
-            try:
-                self._q.put(self._SENTINEL, timeout=0.05)
-                return
-            except queue.Full:
-                continue
+            self._put(self._SENTINEL)
 
     def abandon(self):
         """Consumer gone: unblock producers forever and drain the queue."""

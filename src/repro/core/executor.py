@@ -26,7 +26,9 @@ dispatch of unrelated work).
 
 ``pipelined=False``: the paper's serialized FCFS loop (§4.4/§4.5), kept as
 the measured baseline — one ``Scheduler.schedule`` call per queued step,
-synchronous transfers inside the worker, sleep-polling.  Used by
+synchronous transfers inside the worker (the loop waits for them),
+sleep-polling, and one harvest a tick in launch order, so that a run's
+journal does not depend on thread timing.  Used by
 ``benchmarks/bench_pipeline.py`` to quantify the pipelining win.
 
 Per iteration (both modes):
@@ -59,6 +61,7 @@ from concurrent.futures import wait as futures_wait
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro.core import tracing
 from repro.core.autoscale import AutoscaleConfig, Autoscaler
 from repro.core.connector import deserialize, serialize
 from repro.core.datamanager import DataManager
@@ -109,6 +112,9 @@ class RunResult:
     # ratio is wasted_seconds over total busy seconds
     wasted_seconds: float = 0.0
     wasted_invocations: int = 0
+    # the run's spans (``tracing.Span``) when a JAX profiler session was
+    # active as it started, else None
+    spans: Optional[List[tracing.Span]] = None
 
     def timeline_rows(self) -> List[tuple]:
         t0 = min((e.start for e in self.events), default=0.0)
@@ -142,7 +148,8 @@ class _Command:
         cancel = ctx["environment"].get("__cancel__")
         if cancel is not None and cancel.is_set():
             raise RuntimeError(f"{self.step.path} cancelled pre-start")
-        outputs = self.step.fn(inputs, ctx) or {}
+        with tracing.span("sf:body"):
+            outputs = self.step.fn(inputs, ctx) or {}
         missing = set(self.step.outputs) - set(outputs)
         if missing:
             raise RuntimeError(
@@ -725,10 +732,21 @@ class StreamFlowExecutor:
 
     def _execute(self, workflow, bindings: List[Binding],
                  inputs: Optional[Dict[str, Any]] = None,
-                 collect: bool = True, *,
-                 pre_completed: Optional[set] = None,
-                 pre_tokens: Optional[set] = None,
-                 resumed: bool = False) -> RunResult:
+                 collect: bool = True, **kw) -> RunResult:
+        # while a JAX profiler session runs, the run's spans are kept in
+        # memory and handed out with its result, teardown included
+        with tracing.run_log() as spans:
+            result = self._run_plan(workflow, bindings, inputs, collect, **kw)
+            if spans is not None:
+                result.spans = spans.spans()
+            return result
+
+    def _run_plan(self, workflow, bindings: List[Binding],
+                  inputs: Optional[Dict[str, Any]] = None,
+                  collect: bool = True, *,
+                  pre_completed: Optional[set] = None,
+                  pre_tokens: Optional[set] = None,
+                  resumed: bool = False) -> RunResult:
         t_start = time.time()
         # accepts a Workflow (expanded here) or an already-expanded plan
         # (resume passes one); scalar workflows expand to themselves —
@@ -785,62 +803,71 @@ class StreamFlowExecutor:
                     step, err = next(iter(failed_final.items()))
                     raise RuntimeError(
                         f"step {step} failed after retries") from err
-                # 1. enqueue newly fireable invocations (FCFS arrival order);
-                #    with the cross-run cache on, an invocation whose memo
-                #    entry verifies live is completed here and never queues
-                started = (list(running) + list(completed) + waiting
-                           + [r["path"] for r in retries])
-                memoed = 0
-                for path in plan.fireable(done_tokens, started):
-                    if self.cache is not None and self._try_memo(
-                            plan, path, completed, done_tokens):
-                        memoed += 1
-                        continue
-                    waiting.append(path)
-                    self._transition(path, "fireable")
-                # 2. launch retries whose backoff deadline passed (a step
-                # whose speculative twin finished during the backoff is
-                # already complete — don't re-execute it)
-                now = time.time()
-                due, pending = [], []
-                for r in retries:
-                    if r["path"] in completed:
-                        continue
-                    (due if r["retry_at"] <= now else pending).append(r)
-                retries = pending
-                for r in due:
-                    self._retry(r["rec"], r["path"], running)
-                # 3. schedule the queue (whole-queue batch when pipelined)
-                waiting = self._schedule_queue(
-                    plan, bindings, waiting, running, pool)
-                # 3b. autoscaling: export the unplaced backlog as queue
-                #     pressure, then run one control iteration.  Entirely
-                #     absent without an autoscaler/service — the static
-                #     pool's scheduling is untouched.
-                if self._report_queue:
-                    self.scheduler.note_queue(
-                        self._queue_entries(waiting, bindings), ns=self._ns)
-                if self.autoscaler is not None:
-                    self.autoscaler.tick()
-                # 4. straggler speculation
-                if self.fault.speculative:
-                    self._maybe_speculate(plan, bindings, running, pool)
-                # 5. harvest completions (failures defer into ``retries``)
-                progressed = self._harvest(running, completed, done_tokens,
-                                           failed_final, retries)
-                # 6. grace-period undeploy (beyond-paper)
-                pending = waiting + list(running) + [r["path"]
-                                                    for r in retries]
-                pending_models = set()
-                for p in pending:
-                    b = self._resolve_binding(p.split("#spec")[0], bindings)
-                    pending_models.update(m for m, _ in b.targets)
-                released = self.deployment.maybe_undeploy_idle(pending_models)
-                for m in released:
-                    self.scheduler.forget_model(m)
-                    self.data.drop_model(m)
-                    if self.cache is not None:
-                        self.cache.drop_model(m)
+                with tracing.span("sf:tick"):
+                    # 1. enqueue newly fireable invocations (FCFS arrival
+                    #    order); with the cross-run cache on, an invocation
+                    #    whose memo entry verifies live is completed here
+                    #    and never queues
+                    with tracing.span("sf:fire"):
+                        started = (list(running) + list(completed) + waiting
+                                   + [r["path"] for r in retries])
+                        memoed = 0
+                        for path in plan.fireable(done_tokens, started):
+                            if self.cache is not None and self._try_memo(
+                                    plan, path, completed, done_tokens):
+                                memoed += 1
+                                continue
+                            waiting.append(path)
+                            self._transition(path, "fireable")
+                    # 2. launch retries whose backoff deadline passed (a step
+                    # whose speculative twin finished during the backoff is
+                    # already complete — don't re-execute it)
+                    now = time.time()
+                    due, pending = [], []
+                    for r in retries:
+                        if r["path"] in completed:
+                            continue
+                        (due if r["retry_at"] <= now else pending).append(r)
+                    retries = pending
+                    for r in due:
+                        self._retry(r["rec"], r["path"], running)
+                    # 3. schedule the queue (whole-queue batch when pipelined)
+                    with tracing.span("sf:schedule"):
+                        waiting = self._schedule_queue(
+                            plan, bindings, waiting, running, pool)
+                    # 3b. autoscaling: export the unplaced backlog as queue
+                    #     pressure, then run one control iteration.  Entirely
+                    #     absent without an autoscaler/service — the static
+                    #     pool's scheduling is untouched.
+                    if self._report_queue:
+                        self.scheduler.note_queue(
+                            self._queue_entries(waiting, bindings),
+                            ns=self._ns)
+                    if self.autoscaler is not None:
+                        self.autoscaler.tick()
+                    # 4. straggler speculation
+                    if self.fault.speculative:
+                        self._maybe_speculate(plan, bindings, running, pool)
+                    # 5. harvest completions (failures defer into ``retries``)
+                    with tracing.span("sf:harvest"):
+                        progressed = self._harvest(
+                            running, completed, done_tokens, failed_final,
+                            retries, in_order=not self.pipelined)
+                    # 6. grace-period undeploy (beyond-paper)
+                    pending = waiting + list(running) + [r["path"]
+                                                        for r in retries]
+                    pending_models = set()
+                    for p in pending:
+                        b = self._resolve_binding(p.split("#spec")[0],
+                                                  bindings)
+                        pending_models.update(m for m, _ in b.targets)
+                    released = self.deployment.maybe_undeploy_idle(
+                        pending_models)
+                    for m in released:
+                        self.scheduler.forget_model(m)
+                        self.data.drop_model(m)
+                        if self.cache is not None:
+                            self.cache.drop_model(m)
                 # 7. progress bookkeeping: sleep on the wake event (pipelined)
                 #    or poll (serialized baseline); deadlock guard either way
                 #    (a memo hit is progress: its tokens may fire successors
@@ -1169,31 +1196,69 @@ class StreamFlowExecutor:
         xfer_futs = (self.data.prefetch(tokens, model, resource)
                      if self.pipelined else None)
 
+        # the serialized loop waits for the worker's stage-in: its running
+        # and transfer records then reach the journal in launch order
+        staged = None if self.pipelined else threading.Event()
+        # the worker's spans belong to this invocation (its JobEvent)
+        inv = tracing.invocation(self._ns, key, attempt)
+
         def work():
-            self._transition(path, "running", model=model,
-                             resource=resource, attempt=attempt,
-                             speculative=speculative)
-            if xfer_futs is None:
-                for token in tokens:            # serialized baseline (R3/R4)
-                    self.data.transfer_sync(token, model, resource)
-            else:
-                for f in xfer_futs:
-                    f.result()                  # propagate transfer failures
-            conn = self.deployment.get_connector(model)
-            cmd = _Command(step, self, model, resource)
-            conn.run(resource, cmd, environment={"__cancel__": cancel},
-                     capture_output=False)
+            with tracing.attach(inv), tracing.span("sf:work"):
+                try:
+                    self._transition(path, "running", model=model,
+                                     resource=resource, attempt=attempt,
+                                     speculative=speculative)
+                    with tracing.span("sf:stage_in"):
+                        if xfer_futs is None:
+                            for token in tokens:    # serialized (R3/R4)
+                                self.data.transfer_sync(token, model,
+                                                        resource)
+                        else:
+                            for f in xfer_futs:
+                                f.result()  # propagate transfer failures
+                finally:
+                    if staged is not None:
+                        staged.set()
+                conn = self.deployment.get_connector(model)
+                cmd = _Command(step, self, model, resource)
+                conn.run(resource, cmd, environment={"__cancel__": cancel},
+                         capture_output=False)
             return None
 
         fut = pool.submit(work)
         rec["future"] = fut
         fut.add_done_callback(lambda _f: self._wake.set())
+        if staged is not None:
+            # (a future the pool cancels never runs the worker)
+            while not staged.wait(0.05) and not fut.done():
+                pass
 
     # ---------------------------------------------------------------- harvest
+    @staticmethod
+    def _next_in_order(running, completed) -> List[str]:
+        """The one invocation the serialized loop harvests this tick: the
+        oldest launched, once it has finished, so that what the loop sees
+        (and journals) does not depend on which worker finishes first.  A
+        speculative twin, and a primary whose twin already completed its
+        path, do not hold the others back; a finished twin is taken when
+        the oldest is still running."""
+        for key, rec in running.items():
+            if rec["future"].done():
+                return [key]
+            if not (rec["speculative"]
+                    or key.split("#spec")[0] in completed):
+                break
+        return [k for k, r in running.items()
+                if r["speculative"] and r["future"].done()][:1]
+
     def _harvest(self, running, completed, done_tokens, failed_final,
-                 retries: List[dict]) -> bool:
+                 retries: List[dict], *, in_order: bool = False) -> bool:
+        """Register finished invocations: every one that has finished,
+        or with ``in_order`` one a tick, in launch order (the serialized
+        loop)."""
         progressed = False
-        for key in list(running):
+        for key in (self._next_in_order(running, completed) if in_order
+                    else list(running)):
             rec = running[key]
             fut: Future = rec["future"]
             if not fut.done():

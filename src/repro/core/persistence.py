@@ -47,6 +47,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Set, Tuple
 
+from repro.core import tracing
+
 
 class JournalError(ValueError):
     """Raised for unusable journals (corruption before the tail)."""
@@ -249,15 +251,17 @@ class ExecutionJournal:
 
     # ---------------------------------------------------------------- write
     def append(self, kind: str, **fields):
-        rec = {"v": 1, "t": time.time(), "kind": kind, **fields}
-        line = json.dumps(rec, separators=(",", ":"))
-        with self._lock:
-            if self._fh.closed:
-                return
-            self._fh.write(line + "\n")
-            self._fh.flush()
-            if self.fsync:
-                os.fsync(self._fh.fileno())
+        with tracing.span("sf:journal") as sp:
+            sp.set("kind", kind)
+            rec = {"v": 1, "t": time.time(), "kind": kind, **fields}
+            line = json.dumps(rec, separators=(",", ":"))
+            with self._lock:
+                if self._fh.closed:
+                    return
+                self._fh.write(line + "\n")
+                self._fh.flush()
+                if self.fsync:
+                    os.fsync(self._fh.fileno())
 
     def close(self):
         with self._lock:

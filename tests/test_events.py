@@ -1,6 +1,8 @@
 """Typed event stream (PR 6 tentpole, part a): run_stream over both
 executor loops, backpressure without loss, resume replay, and
 serialized-vs-pipelined event equivalence."""
+import random
+import sys
 import threading
 import time
 
@@ -120,6 +122,55 @@ def test_abandoning_consumer_does_not_wedge_the_run():
     it.close()                      # consumer walks away mid-run
     res = es.result(timeout=30)     # producer must not deadlock on emit
     assert "combined" in res.outputs
+
+
+def test_concurrent_producers_arrive_in_seq_order():
+    """Producers on several threads, each held up for a while as it
+    enqueues: the consumer still sees every event, in the order of its
+    seq."""
+    sink = EventSink(maxsize=4)
+    put, rng = sink._q.put, random.Random(0)
+
+    def late_put(item, *a, **kw):
+        time.sleep(rng.random() * 1e-3)
+        return put(item, *a, **kw)
+    sink._q.put = late_put
+
+    def produce():
+        for _ in range(20):
+            sink.emit(WorkflowStarted())
+    threads = [threading.Thread(target=produce) for _ in range(12)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        got = []
+        for ev in sink.events():
+            got.append(ev.seq)
+            if len(got) == 12 * 20:
+                break
+        for t in threads:
+            t.join(timeout=10)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert got == list(range(12 * 20))
+
+
+def test_abandon_frees_producers_waiting_on_a_full_buffer():
+    sink = EventSink(maxsize=1)
+    sink.emit(WorkflowStarted())          # the buffer is full
+    threads = [threading.Thread(target=sink.emit, args=(WorkflowStarted(),))
+               for _ in range(3)]
+    for t in threads:
+        t.start()
+    time.sleep(0.1)
+    assert all(t.is_alive() for t in threads)
+    sink.abandon()
+    for t in threads:
+        t.join(timeout=5)
+    assert not any(t.is_alive() for t in threads)
 
 
 def test_unconsumed_stream_still_completes():

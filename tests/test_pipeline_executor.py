@@ -61,6 +61,23 @@ def test_pipelined_overlaps_transfers_with_compute():
     assert rp.wall_seconds < rs.wall_seconds - 0.1
 
 
+def test_serialized_loop_harvests_in_launch_order():
+    """Two replicas, the first-launched invocation the slower: the
+    serialized loop harvests it first all the same, so what it journals
+    does not depend on which worker finishes first."""
+    wf = Workflow("order")
+    wf.add_step(Step("/slow", lambda i, c: time.sleep(0.2) or {"a": 1},
+                     {}, ("a",)))
+    wf.add_step(Step("/fast", lambda i, c: {"b": 2}, {}, ("b",)))
+    ex = StreamFlowExecutor(_slow_link_site(replicas=2, latency=0.0),
+                            pipelined=False,
+                            fault=FaultConfig(speculative=False))
+    res = ex.run(wf, [Binding("/", "site", "svc")], {})
+    ev = {e.step: e for e in res.events if e.status == "completed"}
+    assert ev["/slow"].start <= ev["/fast"].start
+    assert ev["/slow"].end <= ev["/fast"].end
+
+
 def test_stage_in_prefetches_before_slot_frees():
     ex, res = _run(pipelined=True)
     rows = res.timeline_rows()
