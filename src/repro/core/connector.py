@@ -13,6 +13,8 @@ device-mesh *sites*.  The contract is kept method-for-method:
 Each resource owns an object store (the container filesystem analogue).
 ``copy`` moves *serialized* payloads so a two-step inter-site transfer has
 real, measurable cost (bytes appear in the DataManager transfer log).
+A token of device arrays stays on the device in the store it was put in
+(a *resident* entry) and becomes bytes only when something asks for bytes.
 """
 from __future__ import annotations
 
@@ -22,8 +24,11 @@ import hashlib
 import pickle
 import threading
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
+
+import jax
+import numpy as np
 
 from repro.core import tracing
 
@@ -51,21 +56,92 @@ def content_digest(payload: bytes) -> str:
     return hashlib.sha256(payload).hexdigest()
 
 
+#: leaves that may sit beside device arrays in a resident value: immutable
+#: scalars, which no consumer can change under another
+_SCALARS = (bool, int, float, complex, str, bytes, np.number, np.bool_)
+
+
+def _resident_leaves(value: Any) -> Optional[list]:
+    """The pytree leaves of a value that a store keeps as it is: device
+    arrays (``jax.Array``, at least one) and immutable scalars.  None for
+    any other value, which is stored as bytes."""
+    try:
+        leaves = jax.tree.leaves(value)
+    except ValueError:          # a dict whose keys do not sort
+        return None
+    if not any(isinstance(x, jax.Array) for x in leaves):
+        return None
+    if not all(isinstance(x, (jax.Array, *_SCALARS)) for x in leaves):
+        return None
+    return leaves
+
+
+def _fresh(x: Any) -> Any:
+    """New containers around the same leaves, each in its own order (a
+    dict keeps its keys' order, so the copy pickles as the original)."""
+    t = type(x)
+    if t is dict:
+        return {k: _fresh(v) for k, v in x.items()}
+    if t is list:
+        return [_fresh(v) for v in x]
+    if t is tuple:
+        return tuple(_fresh(v) for v in x)
+    if x is None or isinstance(x, (jax.Array, *_SCALARS)):
+        return x
+    # any other pytree node (a named tuple, a registered class): one
+    # level of its own flatten gives the children its unflatten takes
+    children, node = jax.tree.flatten(x, is_leaf=lambda y: y is not x)
+    return jax.tree.unflatten(node, [_fresh(c) for c in children])
+
+
+class _Resident:
+    """A value held as it is: a copy of its containers made when it was
+    put, its leaves, their byte size, and the digest of its bytes once
+    something has asked for them."""
+    __slots__ = ("tree", "leaves", "nbytes", "digest", "lock")
+
+    def __init__(self, value: Any, leaves: list):
+        self.tree = _fresh(value)
+        self.leaves = leaves
+        self.nbytes = sum(getattr(x, "nbytes", 0) for x in leaves)
+        self.digest: Optional[str] = None
+        self.lock = threading.Lock()      # one materialization at a time
+
+    def value(self) -> Any:
+        """Fresh containers around the same immutable leaves."""
+        return _fresh(self.tree)
+
+    def dead(self) -> bool:
+        return any(isinstance(x, jax.Array) and x.is_deleted()
+                   for x in self.leaves)
+
+
 class ObjectStore:
     """Per-resource content-addressed payload store with byte accounting.
 
     Paths (token keys) index into a digest-keyed CAS: each distinct payload
     is held once, however many paths reference it, so duplicate puts on a
-    site cost no extra memory and ``size``/``exists``/``digest_of`` answer
-    from the path→digest index alone.  ``name`` identifies the owning
-    resource (or site, for shared stores) so a missed lookup names where
-    the token was expected, not just its key.
+    site cost no extra memory and ``size``/``exists`` answer from the
+    index alone.  ``name`` identifies the owning resource (or site, for
+    shared stores) so a missed lookup names where the token was expected,
+    not just its key.
+
+    Values go in and out through ``put_value``/``get_value``.  A value of
+    device arrays (:func:`_resident_leaves`) is held as it is, a *resident*
+    entry: a consumer on the same store gets fresh containers around the
+    same arrays, with no pickle, hash or host copy.  Its bytes are made
+    once, on the first call that needs them (``get``, ``digest_of``,
+    ``demote``), with the same ``serialize`` and hash as a bytes put;
+    ``release`` lets go of the arrays without making any.  Any other
+    value is serialized and put.
 
     Byte accounting is deliberately *logical*: ``bytes_in``/``bytes_out``
-    count what callers pushed/pulled (every put and get, dedup or not) so
-    transfer metrics are invariant to the CAS internals; the dedup win is
-    visible separately via ``dedup_puts``/``dedup_bytes``/``unique_bytes``.
-    Metadata probes (``exists``/``size``/``digest_of``/``has_digest``/
+    count what callers pushed/pulled (every put and get, dedup or not;
+    ``nbytes`` for a resident value) so transfer metrics are invariant to
+    the CAS internals; the dedup win is visible separately via
+    ``dedup_puts``/``dedup_bytes``/``unique_bytes``, and the resident path
+    via ``resident_puts``/``resident_bytes``/``materialized``.  Metadata
+    probes (``exists``/``size``/``digest_of``/``has_digest``/
     ``link_digest``) never touch the byte counters."""
 
     def __init__(self, name: str = "store"):
@@ -73,11 +149,15 @@ class ObjectStore:
         self._cas: Dict[str, bytes] = {}      # digest -> payload (once)
         self._index: Dict[str, str] = {}      # path -> digest
         self._refs: Dict[str, int] = {}       # digest -> live path count
+        self._resident: Dict[str, _Resident] = {}  # path -> held value
         self._lock = threading.Lock()
         self.bytes_in = 0
         self.bytes_out = 0
         self.dedup_puts = 0     # puts whose payload was already held
         self.dedup_bytes = 0    # bytes those puts did NOT duplicate
+        self.resident_puts = 0  # values held as they are
+        self.resident_bytes = 0  # their nbytes
+        self.materialized = 0   # resident entries later made into bytes
 
     # -- internal (lock held) -------------------------------------------------
     def _bind(self, path: str, digest: str):
@@ -97,14 +177,34 @@ class ObjectStore:
         else:
             self._refs[digest] = n
 
+    def _unbind(self, path: str):
+        self._resident.pop(path, None)
+        digest = self._index.pop(path, None)
+        if digest is not None:
+            self._release(digest)
+
+    def _dead_error(self, path: str) -> RuntimeError:
+        return RuntimeError(
+            f"object store {self.name!r} holds token {path!r} on the "
+            f"device, but its buffers were deleted (a consumer donated "
+            f"them)")
+
     # -- data plane -----------------------------------------------------------
     def put(self, path: str, payload: bytes) -> str:
         """Store a payload under ``path``; returns its content digest.
         A duplicate put (payload already in the CAS) only adds an index
         entry — the bytes are not held twice."""
+        return self._put(path, payload)
+
+    def _put(self, path: str, payload: bytes,
+             held: Optional[_Resident] = None) -> str:
+        """Hash and index a payload: a caller's put, or (``held``) the
+        bytes of a resident entry, which stays held beside them."""
         with tracing.span("sf:cas_put") as sp:
             digest = content_digest(payload)
             with self._lock:
+                if held is not None and self._resident.get(path) is not held:
+                    return digest       # replaced or deleted meanwhile
                 dedup = digest in self._cas
                 if dedup:
                     self.dedup_puts += 1
@@ -112,12 +212,66 @@ class ObjectStore:
                 else:
                     self._cas[digest] = payload
                 self._bind(path, digest)
-                self.bytes_in += len(payload)
+                if held is None:
+                    self._resident.pop(path, None)
+                    self.bytes_in += len(payload)
+                else:
+                    held.digest = digest
             sp.set("bytes", len(payload))
             sp.set("dedup", dedup)
         return digest
 
+    def put_value(self, path: str, value: Any):
+        """Store a value under ``path``: held as it is when it is device
+        arrays (once they are ready, so that device errors surface in the
+        producer), else serialized and put."""
+        leaves = _resident_leaves(value)
+        if leaves is None:
+            self.put(path, serialize(value))
+            return
+        held = _Resident(value, leaves)
+        with tracing.span("sf:hold") as sp:
+            jax.block_until_ready(held.leaves)
+            sp.set("bytes", held.nbytes)
+            sp.set("key", path)
+        with self._lock:
+            self._unbind(path)
+            self._resident[path] = held
+            self.bytes_in += held.nbytes
+            self.resident_puts += 1
+            self.resident_bytes += held.nbytes
+
+    def get_value(self, path: str) -> Any:
+        """The value stored at ``path``: a resident entry's arrays in
+        fresh containers, else the payload deserialized."""
+        with self._lock:
+            held = self._resident.get(path)
+            if held is not None:
+                if held.dead():
+                    raise self._dead_error(path)
+                self.bytes_out += held.nbytes
+        if held is None:
+            return deserialize(self.get(path))
+        return held.value()
+
+    def _materialize(self, path: str):
+        """Make a resident entry's bytes, once; nothing for other paths."""
+        with self._lock:
+            held = self._resident.get(path)
+        if held is None:
+            return
+        with held.lock:
+            if held.digest is not None:
+                return
+            if held.dead():
+                raise self._dead_error(path)
+            payload = serialize(held.tree, lazy=True)
+            with self._lock:
+                self.materialized += 1
+            self._put(path, payload, held)
+
     def get(self, path: str) -> bytes:
+        self._materialize(path)
         with self._lock:
             digest = self._index.get(path)
             payload = self._cas.get(digest) if digest is not None else None
@@ -131,33 +285,76 @@ class ObjectStore:
 
     def exists(self, path: str) -> bool:
         with self._lock:
-            return path in self._index
+            return path in self._index or path in self._resident
 
     def size(self, path: str) -> int:
-        """Byte length of a stored payload, or -1 when absent.  A metadata
-        probe: does NOT touch the bytes_in/bytes_out accounting, so
-        planners may ask freely without polluting transfer metrics."""
+        """Byte length of a stored payload, or -1 when absent.  A resident
+        entry answers its arrays' ``nbytes`` until its bytes are made, and
+        their length after, as a bytes entry does.  A metadata probe: does
+        NOT touch the bytes_in/bytes_out accounting, so planners may ask
+        freely without polluting transfer metrics."""
         with self._lock:
             digest = self._index.get(path)
             if digest is None:
-                return -1
+                held = self._resident.get(path)
+                return -1 if held is None else held.nbytes
             return len(self._cas[digest])
 
     def delete(self, path: str):
         """Drop a path; the payload survives while other paths share its
         digest and is freed with the last reference."""
         with self._lock:
-            digest = self._index.pop(path, None)
-            if digest is not None:
-                self._release(digest)
+            self._unbind(path)
 
     def paths(self) -> List[str]:
         with self._lock:
-            return list(self._index)
+            return list(dict.fromkeys([*self._index, *self._resident]))
+
+    def release(self, prefix: str) -> int:
+        """Let go of the device arrays of the resident entries under
+        ``prefix``, making no bytes: an entry whose bytes were made keeps
+        them, one without is dropped.  Returns the number dropped."""
+        with self._lock:
+            paths = [p for p in self._resident if p.startswith(prefix)]
+            for path in paths:
+                del self._resident[path]
+            return sum(p not in self._index for p in paths)
+
+    def demote(self) -> int:
+        """Turn every resident entry into bytes and let go of its device
+        arrays, so that it stays readable by path; entries whose buffers
+        were donated are dropped.  Returns the number of entries demoted."""
+        with self._lock:
+            paths = list(self._resident)
+        done = 0
+        for path in paths:
+            with self._lock:
+                held = self._resident.get(path)
+                if held is None:
+                    continue
+                if held.dead():
+                    del self._resident[path]
+                    continue
+            self._materialize(path)
+            with self._lock:
+                if self._resident.get(path) is held:
+                    del self._resident[path]
+                    done += 1
+        return done
+
+    def clear(self):
+        """Drop every path and payload (the site is undeployed)."""
+        with self._lock:
+            self._cas.clear()
+            self._index.clear()
+            self._refs.clear()
+            self._resident.clear()
 
     # -- content addressing (all metadata probes: counter-neutral) ------------
     def digest_of(self, path: str) -> Optional[str]:
-        """Content digest stored at ``path``, or None when absent."""
+        """Content digest stored at ``path``, or None when absent.  A
+        resident entry is made into bytes for it."""
+        self._materialize(path)
         with self._lock:
             return self._index.get(path)
 
@@ -173,19 +370,24 @@ class ObjectStore:
         with self._lock:
             if digest not in self._cas:
                 return False
+            self._resident.pop(path, None)
             self._bind(path, digest)
             return True
 
     def unique_bytes(self) -> int:
-        """Bytes physically held (one copy per digest)."""
+        """Payload bytes physically held (one copy per digest)."""
         with self._lock:
             return sum(len(p) for p in self._cas.values())
 
 
-def serialize(value: Any) -> bytes:
+def serialize(value: Any, *, lazy: bool = False) -> bytes:
+    """Pickle a value; ``lazy`` marks the bytes of a resident entry, made
+    when they were first asked for."""
     with tracing.span("sf:encode") as sp:
         payload = pickle.dumps(value, protocol=pickle.HIGHEST_PROTOCOL)
         sp.set("bytes", len(payload))
+        if lazy:
+            sp.set("lazy", True)
     return payload
 
 
@@ -239,6 +441,10 @@ class Connector(abc.ABC):
     @abc.abstractmethod
     def store(self, resource: str) -> ObjectStore:
         ...
+
+    @abc.abstractmethod
+    def stores(self) -> List[ObjectStore]:
+        """The site's distinct stores (one, where they are shared)."""
 
     def copy(self, src: str, dst: str, kind: ConnectorCopyKind,
              source_remote: Optional[str] = None, *,

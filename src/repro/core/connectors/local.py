@@ -36,6 +36,9 @@ class LocalConnector(Connector):
         self.deployed = True
 
     def undeploy(self) -> None:
+        # the stores go with the site, and their device arrays with them
+        for store in set(self._stores.values()):
+            store.clear()
         self._resources.clear()
         self._stores.clear()
         self.deployed = False
@@ -49,6 +52,9 @@ class LocalConnector(Connector):
 
     def store(self, resource: str) -> ObjectStore:
         return self._stores[resource]
+
+    def stores(self) -> List[ObjectStore]:
+        return list({id(s): s for s in self._stores.values()}.values())
 
     def shared_data_space(self) -> bool:
         return self._shared is not None

@@ -68,6 +68,9 @@ class MeshConnector(Connector):
         self.deployed = True
 
     def undeploy(self) -> None:
+        # the stores go with the site, and their device arrays with them
+        for store in set(self._stores.values()):
+            store.clear()
         self._resources.clear()
         self._stores.clear()
         self._meshes.clear()
@@ -83,6 +86,9 @@ class MeshConnector(Connector):
 
     def store(self, resource: str) -> ObjectStore:
         return self._stores[resource]
+
+    def stores(self) -> List[ObjectStore]:
+        return list({id(s): s for s in self._stores.values()}.values())
 
     def shared_data_space(self) -> bool:
         return self._shared is not None

@@ -62,6 +62,9 @@ class SimClusterConnector(Connector):
     def store(self, resource: str) -> ObjectStore:
         return self._inner.store(resource)
 
+    def stores(self) -> List[ObjectStore]:
+        return self._inner.stores()
+
     def shared_data_space(self) -> bool:
         return self._inner.shared_data_space()
 

@@ -567,6 +567,8 @@ class DataManager:
                     continue
                 try:
                     st = conn.store(loc.resource)
+                    if st.size(loc.path) > self.journal.max_payload_bytes:
+                        break       # too large to inline: make no bytes
                     if st.exists(loc.path):
                         raw = st.get(loc.path)
                         break

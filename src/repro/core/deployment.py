@@ -237,6 +237,11 @@ class DeploymentManager:
                 dep.connector.undeploy()
                 self._journal(model_name, "undeploy")
             else:
+                # the site and its tokens outlive this manager (resume
+                # re-attaches to them by path): keep them as bytes, not
+                # as device arrays
+                for store in dep.connector.stores():
+                    store.demote()
                 self._journal(model_name, "detach")
             self.timeline.append((model_name, "undeploy", t0, time.time()))
 

@@ -125,8 +125,10 @@ class RunResult:
 
 class _Command:
     """The Connector 'command': reads input tokens from the resource store,
-    runs the invocation fn, writes outputs back.  ``step`` is a
-    ``workflow.Invocation`` (or a plain Step duck-typing one); ``tag``
+    runs the invocation fn, writes outputs back (device arrays stay on the
+    device, ``ObjectStore.put_value``, so the fn gets the stored arrays
+    themselves and must not donate them: see ``workflow.Step``).  ``step``
+    is a ``workflow.Invocation`` (or a plain Step duck-typing one); ``tag``
     keys fault injection."""
 
     def __init__(self, step, executor: "StreamFlowExecutor",
@@ -143,7 +145,7 @@ class _Command:
         # sharing a pooled site can't collide (or falsely R4-elide) on
         # identical token refs
         key = self._ex._store_key
-        inputs = {port: deserialize(store.get(key(token)))
+        inputs = {port: store.get_value(key(token))
                   for port, token in self.step.inputs.items()}
         cancel = ctx["environment"].get("__cancel__")
         if cancel is not None and cancel.is_set():
@@ -155,7 +157,11 @@ class _Command:
             raise RuntimeError(
                 f"{self.step.path} did not produce tokens {sorted(missing)}")
         for token in self.step.outputs:
-            store.put(key(token), serialize(outputs[token]))
+            store.put_value(key(token), outputs[token])
+            if self._ex.cache is not None:
+                # the memo record keys outputs by digest: make the bytes
+                # here, on the worker, and not on the loop's harvest
+                store.digest_of(key(token))
         return outputs
 
 

@@ -127,8 +127,9 @@ class DeploymentPool:
         self.manager = DeploymentManager(models, grace_period_s=keepalive_s)
         self._lock = threading.RLock()
 
-    def lease_manager(self) -> "PooledDeploymentManager":
-        return PooledDeploymentManager(self)
+    def lease_manager(self, namespace: str = ""
+                      ) -> "PooledDeploymentManager":
+        return PooledDeploymentManager(self, namespace)
 
     def maybe_undeploy_idle(self, pending_models: Optional[set] = None
                             ) -> List[str]:
@@ -161,8 +162,9 @@ class PooledDeploymentManager:
     sites down, and idle eviction is delegated to the pool (which skips
     anything still leased by ANY run)."""
 
-    def __init__(self, pool: DeploymentPool):
+    def __init__(self, pool: DeploymentPool, namespace: str = ""):
         self._pool = pool
+        self.namespace = namespace        # the run's store key prefix
         self._inner = pool.manager
         self._leased: set = set()
         self._lock = threading.RLock()
@@ -186,6 +188,13 @@ class PooledDeploymentManager:
             if model_name not in self._leased:
                 return
             self._leased.discard(model_name)
+        conn = self._inner.get_connector(model_name)
+        if conn is not None:
+            # the site outlives the run, and no later run asks for its
+            # keys by path: let go of its device arrays, keeping the bytes
+            # already made (the memo cache and content routing find those)
+            for store in conn.stores():
+                store.release(self.namespace)
         self._inner.release(model_name)
         if self.journal is not None:
             self.journal.deployment(model_name, "detach")
@@ -506,9 +515,9 @@ class WorkflowService:
         if run.checkpoint is not None:
             kw["checkpoint"] = run.checkpoint
         if self.pool is not None:
-            kw["deployment"] = self.pool.lease_manager()
-            kw["scheduler"] = self.scheduler
             kw["namespace"] = f"{run.id}/"
+            kw["deployment"] = self.pool.lease_manager(kw["namespace"])
+            kw["scheduler"] = self.scheduler
         if self.autoscaler is not None:
             # the service owns the ONE control loop; runs just feed it
             # queue pressure and expose their data planes for stage-off

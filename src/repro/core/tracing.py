@@ -34,9 +34,12 @@ Span names (one place each, where the work happens):
 ``sf:work``        the worker function of one invocation
 ``sf:stage_in``    waiting for (or running) the invocation's transfers
 ``sf:body``        the step's own function
-``sf:encode``      ``serialize``; ``bytes``
+``sf:encode``      ``serialize``; ``bytes``, and ``lazy`` for the bytes of
+                   a resident entry, made when first asked for
 ``sf:decode``      ``deserialize``; ``bytes``
 ``sf:cas_put``     ``ObjectStore.put``: hash and index; ``bytes``, ``dedup``
+``sf:hold``        ``ObjectStore.put_value`` of device arrays: waiting until
+                   they are ready; ``bytes``, ``key``
 ``sf:transfer``    ``DataManager.transfer_sync``; ``kind``, ``bytes``
 ``sf:journal``     one journal record, write, flush and fsync; ``kind``
 ``sf:deploy``      a site's deploy or undeploy; ``model``, ``event``

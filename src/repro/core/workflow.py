@@ -117,6 +117,11 @@ class Requirements:
 
 @dataclass
 class Step:
+    """One step of a workflow.  ``fn`` must not donate its inputs (a
+    ``jax.jit`` ``donate_argnums``): a device-array input is the stored
+    token itself, which every other consumer, a retry or speculative twin
+    of this one, and a later collect or copy read too.  Once donated, each
+    of them fails with an error naming the token and its store."""
     path: str                                   # POSIX id, unique in workflow
     fn: Callable[..., Dict[str, Any]]           # (inputs, ctx) -> outputs
     inputs: Dict[str, str] = field(default_factory=dict)   # slot -> port
