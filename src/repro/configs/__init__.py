@@ -22,6 +22,7 @@ _ARCH_MODULES: Dict[str, str] = {
     "recurrentgemma-9b": "recurrentgemma_9b",
     "granite-moe-3b-a800m": "granite_moe_3b_a800m",
     "mixtral-8x7b": "mixtral_8x7b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 ARCH_IDS: List[str] = list(_ARCH_MODULES)

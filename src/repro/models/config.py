@@ -20,6 +20,7 @@ CROSS = "cross"          # cross-attention to modality embeddings (VLM)
 MLSTM = "mlstm"          # xLSTM matrix-memory block
 SLSTM = "slstm"          # xLSTM scalar-memory block (sequential)
 RGLRU = "rglru"          # RG-LRU recurrent block (Griffin / RecurrentGemma)
+MLA = "mla"              # multi-head latent attention (DeepSeek-V2/V3)
 
 
 @dataclass(frozen=True)
@@ -42,9 +43,27 @@ class ArchConfig:
     # Pattern-based stacks (hybrid / xlstm / vlm). Empty => uniform decoder.
     block_pattern: Tuple[str, ...] = ()
     # MoE.
-    n_experts: int = 0
+    n_experts: int = 0               # the router's width
     top_k: int = 0
     capacity_factor: float = 1.25
+    # DeepSeekMoE (DeepSeek-V3 block).  ``experts_held`` > 0 selects the
+    # dropless sigmoid-routed layer that holds experts [expert_offset,
+    # expert_offset + experts_held) of the router's n_experts: one chip's
+    # share of an expert-parallel layer (all of them when experts_held ==
+    # n_experts).
+    experts_held: int = 0
+    expert_offset: int = 0
+    moe_d_ff: int = 0                # expert width (0: d_ff)
+    n_shared_experts: int = 0        # shared SwiGLU of n_shared * moe_d_ff
+    first_k_dense: int = 0           # leading layers with a d_ff SwiGLU
+    routed_scale: float = 1.0        # gates times this
+    norm_topk: bool = True           # gates normalised over the top_k
+    balance_alpha: float = 0.0       # sequence-wise balance loss weight
+    # Latent attention (MLA): kv_lora_rank > 0 with block kind MLA.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # Modality / topology extras.
     encoder_only: bool = False       # e.g. hubert — no decode step
     modality: str = "text"           # text | audio | vision
@@ -76,16 +95,20 @@ class ArchConfig:
     @property
     def n_scan_blocks(self) -> int:
         """Number of scanned super-blocks (each = one pattern period)."""
-        return self.n_layers // self.pattern_period
+        return (self.n_layers - self.first_k_dense) // self.pattern_period
 
     @property
     def n_tail_layers(self) -> int:
         """Layers left over after scanned super-blocks (unrolled at the end)."""
-        return self.n_layers % self.pattern_period
+        return (self.n_layers - self.first_k_dense) % self.pattern_period
 
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
     def param_count(self) -> int:
         """Analytic parameter count (matches init exactly; used for 6ND)."""
@@ -99,7 +122,7 @@ class ArchConfig:
     def reduced(self) -> "ArchConfig":
         """Smoke-test variant: same family/topology, tiny dims."""
         period = self.pattern_period
-        n_layers = max(2 * period, period)  # >=2 periods exercises scan+tail? keep scan only
+        n_layers = self.first_k_dense + 2 * period
         d_model = 64
         n_heads = max(2, min(4, self.n_heads))
         while d_model % n_heads:
@@ -119,6 +142,13 @@ class ArchConfig:
             vocab_size=256,
             n_experts=min(self.n_experts, 4),
             top_k=min(self.top_k, 2),
+            experts_held=min(self.experts_held, 4),
+            expert_offset=0,
+            moe_d_ff=32 if self.moe_d_ff else 0,
+            kv_lora_rank=min(self.kv_lora_rank, 32),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 16),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 8),
+            v_head_dim=min(self.v_head_dim, 8),
             window=64,
             frontend_dim=min(self.frontend_dim, 32) if self.frontend_dim else 0,
             n_patches=min(self.n_patches, 16) if self.n_patches else 0,

@@ -91,14 +91,16 @@ def attention(q, k, v, *, causal: bool, window: int = 0, q_offset=0,
               kv_len: Optional[jax.Array] = None):
     """Grouped-query attention without materialising full S_q x S_k scores.
 
-    q: (B, S_q, H, Dh); k, v: (B, S_k, KH, Dh).  H = KH * G.
+    q, k: (B, S_q|S_k, H|KH, Dh); v: (B, S_k, KH, Dv).  H = KH * G.  The
+    value width Dv may differ from the query/key width Dh (latent
+    attention); scores scale by 1/sqrt(Dh).
     ``q_offset``: absolute position of q[0] relative to k[0] (decode/prefill).
     ``window`` > 0 restricts attention to the last ``window`` key positions.
     ``kv_len``: optional dynamic number of valid key slots (decode caches).
-    Returns (B, S_q, H, Dh).
+    Returns (B, S_q, H, Dv).
     """
     B, Sq, H, Dh = q.shape
-    Sk, KH = k.shape[1], k.shape[2]
+    Sk, KH, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KH
     scale = 1.0 / math.sqrt(Dh)
     qg = q.reshape(B, Sq, KH, G, Dh)
@@ -134,8 +136,8 @@ def attention(q, k, v, *, causal: bool, window: int = 0, q_offset=0,
             return None, block_out(blk, pos)
 
         _, outs = lax.scan(body, None, (qb, jnp.arange(nblk)))
-        out = outs.swapaxes(0, 1).reshape(B, Sq, KH, G, Dh)
-    return out.reshape(B, Sq, H, Dh)
+        out = outs.swapaxes(0, 1).reshape(B, Sq, KH, G, Dv)
+    return out.reshape(B, Sq, H, Dv)
 
 
 def self_attention_block(x, p, cfg, *, positions, causal=True, window=0,

@@ -1,6 +1,6 @@
-"""Mixture-of-Experts blocks (Mixtral / Granite-MoE families).
+"""Mixture-of-Experts blocks (Mixtral / Granite-MoE / DeepSeek-V3 families).
 
-Two dispatch strategies, selectable per call:
+Three dispatch strategies:
 
 * ``einsum``  — T5X/Switch-style capacity-bucketed one-hot dispatch.  This is
   the *baseline*: robust, compiles everywhere, but spends extra HLO FLOPs on
@@ -8,10 +8,18 @@ Two dispatch strategies, selectable per call:
 * ``gather``  — capacity-indexed gather/scatter dispatch: only the active
   expert matmuls cost FLOPs.  This is the beyond-baseline path whose TPU twin
   is the ``moe_gmm`` Pallas grouped-matmul kernel.
+* held share (``moe_held_block``) — the DeepSeekMoE layer of configs with
+  ``experts_held`` > 0: sigmoid router scores over all ``n_experts`` with a
+  selection bias, gates normalised over the top-k and scaled, a shared
+  expert, and a dropless grouped matmul (``jax.lax.ragged_dot``) over only
+  the experts this chip holds.  It is one chip's share of an
+  expert-parallel layer, run without its exchange.
 
-Experts are tensor-parallel on the mesh "model" axis (d_ff sliced), tokens
-stay data-parallel, so no all-to-all is required for either strategy; the EP
-all-to-all variant is discussed in EXPERIMENTS.md §Perf.
+The first two apply softmax top-k routing with a Switch load-balancing loss
+and drop assignments past a capacity.  Their experts are tensor-parallel on
+the mesh "model" axis (d_ff sliced), tokens stay data-parallel, so neither
+needs an all-to-all; the held share is where one would go (PERF.md, Open
+questions).
 
 The dispatch/combine one-hots are never materialised at rank 5: they are
 expressed as iota-compare multiply-reduces so XLA loop-fuses them into
@@ -25,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models.layers import act_fn, rms_norm
+from repro.models.layers import act_fn, gated_mlp, rms_norm
 
 
 def init_moe_ffn_axes():
@@ -162,3 +170,97 @@ def moe_block(x, p, cfg, *, dispatch: str = "einsum",
     else:
         y, aux = moe_einsum(h, p, cfg)
     return x + y.reshape(B, S, d), aux
+
+
+# ---------------------------------------------------------------------------
+# held share — DeepSeekMoE, dropless, this chip's experts only
+# ---------------------------------------------------------------------------
+
+def held_rows(tokens: int, cfg) -> int:
+    """Rows of the grouped matmul: every (token, expert) assignment that
+    can fall on a held expert, so none is ever dropped (the top-k experts
+    of a token are distinct)."""
+    return tokens * min(cfg.top_k, cfg.experts_held)
+
+
+def route_sigmoid(h, p, cfg):
+    """Scores, selection and gates of the DeepSeek-V3 router.
+
+    h: (T, d).  Returns (scores (T, E) f32, idx (T, k), gates (T, k) f32):
+    the top-k of ``scores + bias`` are selected, and the gates are taken
+    from the scores alone, normalised over the k and scaled.  The bias only
+    steers selection and takes no gradient."""
+    # float32 products: the TPU's default precision multiplies f32 in one
+    # bf16 pass, and selection turns on near-ties of the 64 scores
+    logits = jnp.einsum("td,de->te", h.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(scores + lax.stop_gradient(p["bias"]), cfg.top_k)
+    sel = jnp.take_along_axis(scores, idx, axis=-1)
+    if cfg.norm_topk:
+        sel = sel / jnp.sum(sel, axis=-1, keepdims=True)
+    return scores, idx, cfg.routed_scale * sel
+
+
+def sequence_balance_loss(scores, idx, B: int, S: int, cfg):
+    """DeepSeek-V3's sequence-wise balance loss over all E experts,
+    weighted by ``balance_alpha`` and averaged over the B sequences:
+    alpha * sum_i f_i P_i with f_i = E / (k S) * #(tokens that select i)
+    and P_i the mean over tokens of the score normalised over E."""
+    E, k = cfg.n_experts, cfg.top_k
+    norm = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    P = jnp.mean(norm.reshape(B, S, E), axis=1)
+    chosen = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32), axis=1)
+    f = jnp.sum(chosen.reshape(B, S, E), axis=1) * (E / (k * S))
+    return cfg.balance_alpha * jnp.mean(jnp.sum(f * P, axis=-1))
+
+
+def moe_held_block(x, p, cfg):
+    """Pre-norm DeepSeekMoE residual block on the held experts. x: (B,S,d).
+
+    Returns (x, balance_loss, stats): ``stats`` holds the assignments
+    computed per held expert (``expert_load``, (experts_held,)), the held
+    assignments not computed (``dropped``, 0 by construction) and each
+    token's selected experts (``routing``, (B, S, k))."""
+    B, S, d = x.shape
+    T, k, Hn = B * S, cfg.top_k, cfg.experts_held
+    h = rms_norm(x, p["ln"], cfg.norm_eps).reshape(T, d)
+    with jax.named_scope("moe_route"):
+        scores, idx, gates = route_sigmoid(h, p, cfg)
+        aux = sequence_balance_loss(scores, idx, B, S, cfg)
+    with jax.named_scope("moe_dispatch"):
+        local = idx.reshape(-1) - cfg.expert_offset
+        held = (local >= 0) & (local < Hn)
+        slot = jnp.where(held, local, Hn)            # Hn: not held here
+        order = jnp.argsort(slot, stable=True)       # held first, by expert
+        M = held_rows(T, cfg)
+        rows = order[:M]
+        load = jnp.sum(jax.nn.one_hot(slot, Hn + 1, dtype=jnp.int32),
+                       axis=0)[:Hn]
+        n_held = jnp.sum(load)
+        ends = jnp.minimum(jnp.cumsum(load), M)
+        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        valid = jnp.arange(M) < jnp.minimum(n_held, M)
+        tok = rows // k
+        # the TPU's ragged dot writes no row past its groups, forward or
+        # transposed: each operand and result is kept to the valid rows,
+        # so that what those rows hold reaches no output or gradient
+        xs = jnp.where(valid[:, None], h[tok], 0)
+        gw = jnp.where(valid, gates.reshape(-1)[rows], 0.0)
+    with jax.named_scope("moe_experts"):
+        def grouped(x, w):
+            y = lax.ragged_dot(x, w.astype(h.dtype), sizes)
+            return jnp.where(valid[:, None], y, 0)
+        a, b = grouped(xs, p["w1"]), grouped(xs, p["w3"])
+        ys = grouped(act_fn(cfg.act)(a) * b, p["w2"])
+    with jax.named_scope("moe_combine"):
+        y = jnp.zeros((T, d), jnp.float32).at[tok].add(
+            ys.astype(jnp.float32) * gw[:, None])
+    if "shared" in p:
+        with jax.named_scope("moe_shared"):
+            y = y + gated_mlp(h, p["shared"], cfg.act).astype(jnp.float32)
+    stats = {"expert_load": load,
+             "dropped": jnp.maximum(n_held - M, 0).astype(jnp.int32),
+             "routing": idx.reshape(B, S, k).astype(jnp.int32)}
+    return x + y.astype(x.dtype).reshape(B, S, d), aux, stats

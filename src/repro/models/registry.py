@@ -39,13 +39,18 @@ def params_and_axes_shapes(cfg: ArchConfig):
 
 
 def _is_expert_leaf(path) -> bool:
+    """A routed expert tensor: not a leading dense layer's MLP, not the
+    shared experts."""
     keys = [getattr(p, "key", getattr(p, "name", None)) for p in path]
-    return "ffn" in keys and keys[-1] in ("w1", "w2", "w3")
+    return ("ffn" in keys and keys[-1] in ("w1", "w2", "w3")
+            and keys[0] != "lead" and "shared" not in keys)
 
 
 def count_params_analytic(cfg: ArchConfig, active_only: bool = False) -> int:
-    """Exact parameter count (from init shapes).  ``active_only`` scales MoE
-    expert tensors by top_k/E (the per-token active fraction)."""
+    """Exact parameter count (from init shapes).  ``active_only`` scales
+    routed expert tensors by top_k/E, E the router's width: a tensor of
+    ``held`` experts meets top_k * held / E assignments a token, each
+    1 / held of it."""
     shapes, _ = params_and_axes_shapes(cfg)
     leaves = jax.tree_util.tree_flatten_with_path(shapes)[0]
     total = 0

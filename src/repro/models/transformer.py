@@ -6,9 +6,11 @@ depth, which is what makes 62-layer/33B dry-run compiles tractable — plus
 ``n_tail_layers`` unrolled leftovers.
 
 Supported block kinds (see ``repro.models.config``): ATTN / SWA / LOCAL
-(GQA self-attention), CROSS (gated cross-attention to modality memory),
-MLSTM / SLSTM (xLSTM), RGLRU (Griffin).  Channel mixer per layer: gated MLP,
-MoE, or none (d_ff == 0); sLSTM carries its own post-MLP.
+(GQA self-attention), MLA (latent attention), CROSS (gated cross-attention
+to modality memory), MLSTM / SLSTM (xLSTM), RGLRU (Griffin).  Channel mixer
+per layer: gated MLP, MoE, or none (d_ff == 0); sLSTM carries its own
+post-MLP.  ``first_k_dense`` leading layers (unrolled, before the scanned
+periods) take a gated MLP of width d_ff where the rest take MoE.
 
 Public API:
   init_params(rng, cfg)            -> (params, logical_axes)
@@ -30,10 +32,10 @@ from jax import lax
 from repro.models import xlstm as xl
 from repro.models import rglru as rg
 from repro.models.config import (ATTN, SWA, LOCAL, CROSS, MLSTM, SLSTM, RGLRU,
-                                 ArchConfig)
-from repro.models.layers import (attention, dense, rms_norm, rope,
+                                 MLA, ArchConfig)
+from repro.models.layers import (attention, dense, gated_mlp, rms_norm, rope,
                                  decode_attention_block)
-from repro.models.moe import moe_block, init_moe_ffn_axes
+from repro.models.moe import moe_block, moe_held_block, init_moe_ffn_axes
 
 XENT_CHUNK = 512  # sequence chunk for the fused logits+loss scan
 
@@ -99,10 +101,59 @@ def _init_moe(rng, cfg):
     return p, axes
 
 
+def _init_moe_held(rng, cfg):
+    """The held share of a DeepSeekMoE layer: a router over all n_experts
+    with its selection bias, the experts_held routed experts, and the
+    shared experts as one gated MLP."""
+    d, f, E, Hn = cfg.d_model, cfg.expert_d_ff, cfg.n_experts, cfg.experts_held
+    ks = jax.random.split(rng, 5)
+    p = {
+        "ln": jnp.zeros((d,), jnp.float32),
+        "router": _lin(ks[0], d, E),
+        "bias": jnp.zeros((E,), jnp.float32),
+        "w1": jax.random.normal(ks[1], (Hn, d, f), jnp.float32) / math.sqrt(d),
+        "w3": jax.random.normal(ks[2], (Hn, d, f), jnp.float32) / math.sqrt(d),
+        "w2": jax.random.normal(ks[3], (Hn, f, d), jnp.float32) / (
+            math.sqrt(f) * math.sqrt(2 * cfg.n_layers)),
+    }
+    axes = {"ln": ("embed",), "router": ("embed", None), "bias": (None,),
+            **init_moe_ffn_axes()}
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        k1, k3, k2 = jax.random.split(ks[4], 3)
+        p["shared"] = {"w1": _lin(k1, d, fs), "w3": _lin(k3, d, fs),
+                       "w2": _lin(k2, fs, d,
+                                  scale=1.0 / math.sqrt(2 * cfg.n_layers))}
+        axes["shared"] = {"w1": ("embed", "mlp"), "w3": ("embed", "mlp"),
+                          "w2": ("mlp", "embed")}
+    return p, axes
+
+
+def _init_mla(rng, cfg):
+    """Latent attention with no query compression: W_q (d, H*(nope+rope)),
+    W_kva (d, rank+rope) with the latent's norm, W_kvb (rank, H*(nope+v)),
+    W_o (H*v, d)."""
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(rng, 4)
+    p = {
+        "ln": jnp.zeros((d,), jnp.float32),
+        "wq": _lin(ks[0], d, H * (dn + dr)),
+        "wkva": _lin(ks[1], d, r + dr),
+        "kv_ln": jnp.zeros((r,), jnp.float32),
+        "wkvb": _lin(ks[2], r, H * (dn + dv)),
+        "wo": _lin(ks[3], H * dv, d, scale=1.0 / math.sqrt(2 * cfg.n_layers)),
+    }
+    axes = {"ln": ("embed",), "wq": ("embed", "heads"), "wkva": ("embed", None),
+            "kv_ln": (None,), "wkvb": (None, "heads"), "wo": ("heads", "embed")}
+    return p, axes
+
+
 _MIX_INIT = {
     ATTN: _init_attn, SWA: _init_attn, LOCAL: _init_attn,
     CROSS: partial(_init_attn, cross=True),
     MLSTM: xl.init_mlstm, SLSTM: xl.init_slstm, RGLRU: rg.init_rglru,
+    MLA: _init_mla,
 }
 
 
@@ -112,16 +163,18 @@ def _kind_has_ffn(kind: str, cfg: ArchConfig) -> bool:
     return cfg.is_moe or cfg.d_ff > 0
 
 
-def _init_layer(rng, cfg, kind: str):
+def _init_layer(rng, cfg, kind: str, dense_ffn: bool = False):
     k1, k2 = jax.random.split(rng)
     mix, mix_axes = _MIX_INIT[kind](k1, cfg)
     layer = {"mix": mix}
     axes = {"mix": mix_axes}
     if _kind_has_ffn(kind, cfg):
-        if cfg.is_moe:
-            layer["ffn"], axes["ffn"] = _init_moe(k2, cfg)
-        else:
+        if dense_ffn or not cfg.is_moe:
             layer["ffn"], axes["ffn"] = _init_mlp(k2, cfg)
+        elif cfg.experts_held:
+            layer["ffn"], axes["ffn"] = _init_moe_held(k2, cfg)
+        else:
+            layer["ffn"], axes["ffn"] = _init_moe(k2, cfg)
     return layer, axes
 
 
@@ -153,6 +206,15 @@ def init_params(rng, cfg: ArchConfig):
     if cfg.modality == "vision":
         params["vis_proj"] = _lin(ks[1], cfg.frontend_dim, cfg.d_model)
         axes["vis_proj"] = (None, "embed")
+
+    # leading dense layers, unrolled
+    if cfg.first_k_dense:
+        lead_keys = jax.random.split(ks[5], cfg.first_k_dense)
+        params["lead"], axes["lead"] = [], []
+        for k in lead_keys:
+            lp, la = _init_layer(k, cfg, cfg.block_pattern[0], dense_ffn=True)
+            params["lead"].append(lp)
+            axes["lead"].append(la)
 
     # scanned super-blocks: stacked (n_scan, ...) leaves via vmap'd init
     n_scan = cfg.n_scan_blocks
@@ -195,6 +257,8 @@ def _window_for(kind: str, cfg: ArchConfig) -> int:
 
 def _apply_mix(kind, x, p, cfg, ctx, collect: bool):
     """Returns (x, kv_or_state_or_None)."""
+    if kind == MLA:
+        return _mla(x, p, cfg, positions=ctx["positions"])
     if kind in (ATTN, SWA, LOCAL):
         return _self_attn(x, p, cfg, positions=ctx["positions"],
                           causal=not cfg.encoder_only,
@@ -239,6 +303,35 @@ def _self_attn(x, p, cfg, *, positions, causal, window, kernel_mode,
     return x + dense(o.reshape(B, S, H * Dh), p["wo"]), (k, v)
 
 
+def _mla_qkv(h, p, cfg, positions):
+    """Per-head q, k (nope then rope columns) and v of latent attention.
+    The rope part of k is one (B, S, 1, rope) key shared by every head."""
+    B, S, _ = h.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = dense(h, p["wq"]).reshape(B, S, H, dn + dr)
+    kva = dense(h, p["wkva"])
+    c = rms_norm(kva[..., :r], p["kv_ln"], cfg.norm_eps)
+    kr = rope(kva[..., None, r:], positions, cfg.rope_theta)
+    kv = dense(c, p["wkvb"]).reshape(B, S, H, dn + dv)
+    q = jnp.concatenate(
+        [q[..., :dn], rope(q[..., dn:], positions, cfg.rope_theta)], -1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(kr, (B, S, H, dr))], -1)
+    return q, k, kv[..., dn:]
+
+
+def _mla(x, p, cfg, *, positions):
+    """Pre-norm latent-attention residual block (causal)."""
+    with jax.named_scope("mla"):
+        h = rms_norm(x, p["ln"], cfg.norm_eps)
+        B, S, _ = h.shape
+        q, k, v = _mla_qkv(h, p, cfg, positions)
+        o = attention(q, k, v, causal=True)
+        o = dense(o.reshape(B, S, cfg.n_heads * cfg.v_head_dim), p["wo"])
+    return x + o, (k, v)
+
+
 def _cross_attn(x, p, cfg, *, memory):
     h = rms_norm(x, p["ln"], cfg.norm_eps)
     B, S, _ = h.shape
@@ -253,60 +346,102 @@ def _cross_attn(x, p, cfg, *, memory):
 
 
 def _apply_ffn(x, p, cfg, ctx):
-    """Channel mixer. Returns (x, aux_loss)."""
-    if cfg.is_moe:
-        return moe_block(x, p, cfg, dispatch=ctx["moe_dispatch"],
-                         kernel_mode=ctx["kernel_mode"])
-    h = rms_norm(x, p["ln"], cfg.norm_eps)
-    from repro.models.layers import gated_mlp
-    return x + gated_mlp(h, p, cfg.act), jnp.float32(0.0)
+    """Channel mixer. Returns (x, aux_loss, stats): ``stats`` holds the
+    held-share layer's counters, and is empty for every other mixer."""
+    if "router" not in p:
+        h = rms_norm(x, p["ln"], cfg.norm_eps)
+        return x + gated_mlp(h, p, cfg.act), jnp.float32(0.0), {}
+    if cfg.experts_held:
+        return moe_held_block(x, p, cfg)
+    x, aux = moe_block(x, p, cfg, dispatch=ctx["moe_dispatch"],
+                       kernel_mode=ctx["kernel_mode"])
+    return x, aux, {}
 
 
 def _apply_layer(kind, x, layer, cfg, ctx, collect: bool = False):
-    """Returns (x, aux, kv_or_state_or_None)."""
+    """Returns (x, aux, kv_or_state_or_None, stats)."""
     x, kv = _apply_mix(kind, x, layer["mix"], cfg, ctx, collect)
-    aux = jnp.float32(0.0)
+    aux, stats = jnp.float32(0.0), {}
     if "ffn" in layer:
-        x, aux = _apply_ffn(x, layer["ffn"], cfg, ctx)
-    return x, aux, kv
+        x, aux, stats = _apply_ffn(x, layer["ffn"], cfg, ctx)
+    return x, aux, kv, stats
+
+
+def _moe_stats(scanned, tail):
+    """The held-share counters of every MoE layer in depth order:
+    ``expert_load`` (layers, experts_held), ``routing`` (layers, B, S, k)
+    and the total ``dropped``.  ``scanned`` maps a period position to its
+    counters stacked over the scan; ``tail`` lists the tail layers'."""
+    parts = []
+    if scanned:
+        pos = sorted(scanned, key=lambda n: int(n[1:]))
+        parts.append({k: jnp.stack([scanned[n][k] for n in pos], axis=1)
+                      .reshape((-1,) + scanned[pos[0]][k].shape[1:])
+                      for k in scanned[pos[0]]})
+    parts += [{k: v[None] for k, v in st.items()} for st in tail]
+    if not parts:
+        return {}
+    out = {k: jnp.concatenate([p[k] for p in parts]) for k in parts[0]}
+    out["dropped"] = jnp.sum(out["dropped"])
+    return out
 
 
 def _stack_forward(params, cfg, x, ctx, *, collect_kv: bool = False):
-    """Runs the scanned super-blocks + tail. Returns (x, aux, kvs)."""
+    """Runs the leading dense layers, the scanned super-blocks and the
+    tail.  Returns (x, aux, (kvs, tail_kvs, lead_kvs), stats)."""
     remat_policy = {
         "full": None,
         "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         "none": jax.checkpoint_policies.everything_saveable,
     }[cfg.remat]
 
+    def lead_body(x, layer):
+        x, _, kv, _ = _apply_layer(cfg.block_pattern[0], x, layer, cfg, ctx,
+                                   collect=collect_kv)
+        return x, (kv if collect_kv else None)
+
     def period_body(carry, blk):
         x, aux = carry
-        kvs = {}
+        kvs, stats = {}, {}
         for i, kind in enumerate(cfg.block_pattern):
-            x, a, kv = _apply_layer(kind, x, blk[f"l{i}"], cfg, ctx,
-                                    collect=collect_kv)
+            x, a, kv, st = _apply_layer(kind, x, blk[f"l{i}"], cfg, ctx,
+                                        collect=collect_kv)
             aux = aux + a
             if collect_kv and kv is not None:
                 kvs[f"l{i}"] = kv
-        return (x, aux), kvs
+            if st:
+                stats[f"l{i}"] = st
+        return (x, aux), (kvs, stats)
 
     if cfg.remat != "none":
+        lead_body = jax.checkpoint(lead_body, policy=remat_policy,
+                                   prevent_cse=False)
         period_body = jax.checkpoint(
             period_body, policy=remat_policy,
             prevent_cse=False)
 
+    lead_kvs = []
+    for layer in params.get("lead", []):
+        x, kv = lead_body(x, layer)
+        if collect_kv and kv is not None:
+            lead_kvs.append(kv)
     aux = jnp.float32(0.0)
-    kvs = None
+    kvs, scan_stats = None, {}
     if cfg.n_scan_blocks:
-        (x, aux), kvs = lax.scan(period_body, (x, aux), params["blocks"])
-    tail_kvs = []
+        (x, aux), (kvs, scan_stats) = lax.scan(period_body, (x, aux),
+                                               params["blocks"])
+    tail_kvs, tail_stats = [], []
     for i, layer in enumerate(params.get("tail", [])):
         kind = cfg.block_pattern[i % cfg.pattern_period]
-        x, a, kv = _apply_layer(kind, x, layer, cfg, ctx, collect=collect_kv)
+        x, a, kv, st = _apply_layer(kind, x, layer, cfg, ctx,
+                                    collect=collect_kv)
         aux = aux + a
         if collect_kv and kv is not None:
             tail_kvs.append(kv)
-    return x, aux, (kvs, tail_kvs)
+        if st:
+            tail_stats.append(st)
+    return (x, aux, (kvs, tail_kvs, lead_kvs),
+            _moe_stats(scan_stats, tail_stats))
 
 
 # ---------------------------------------------------------------------------
@@ -421,14 +556,20 @@ def forward_train(params, cfg: ArchConfig, batch, *, kernel_mode="reference",
     ctx = make_ctx(cfg, kernel_mode=kernel_mode, moe_dispatch=moe_dispatch,
                    positions=jnp.arange(S), memory=memory, mesh=mesh)
     x = shard_hint(x, ctx, ("batch", None, None))
-    x, aux, _ = _stack_forward(params, cfg, x, ctx)
+    x, aux, _, stats = _stack_forward(params, cfg, x, ctx)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps)
     mask = batch.get("mask")
     loss, acc = softmax_xent_from_hidden(
         x, _head_matrix(params, cfg), batch["labels"], mask)
-    n_layers_moe = cfg.n_layers if cfg.is_moe else 0
-    total = loss + (aux_weight * aux / max(n_layers_moe, 1) if cfg.is_moe else 0.0)
-    return total, {"nll": loss, "aux": aux, "acc": acc}
+    if cfg.experts_held:
+        # the held-share layers' sequence-wise balance losses, each
+        # weighted by balance_alpha already, summed over the layers
+        total = loss + aux
+    else:
+        n_layers_moe = cfg.n_layers if cfg.is_moe else 0
+        total = loss + (aux_weight * aux / max(n_layers_moe, 1)
+                        if cfg.is_moe else 0.0)
+    return total, {"nll": loss, "aux": aux, "acc": acc, **stats}
 
 
 def forward_logits(params, cfg: ArchConfig, batch, *, kernel_mode="reference",
@@ -439,7 +580,7 @@ def forward_logits(params, cfg: ArchConfig, batch, *, kernel_mode="reference",
     ctx = make_ctx(cfg, kernel_mode=kernel_mode, moe_dispatch=moe_dispatch,
                    positions=jnp.arange(S), memory=memory, mesh=mesh)
     x = shard_hint(x, ctx, ("batch", None, None))
-    x, _, _ = _stack_forward(params, cfg, x, ctx)
+    x, _, _, _ = _stack_forward(params, cfg, x, ctx)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps)
     head = _head_matrix(params, cfg)
     return jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
@@ -456,6 +597,11 @@ def _cache_len(kind: str, cfg: ArchConfig, ctx_len: int) -> int:
 
 def _init_layer_state(kind, cfg, B, ctx_len, dt=jnp.bfloat16):
     KH, Dh = cfg.n_kv_heads, cfg.head_dim
+    if kind == MLA:
+        H = cfg.n_heads
+        dqk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        return {"k": jnp.zeros((B, ctx_len, H, dqk), dt),
+                "v": jnp.zeros((B, ctx_len, H, cfg.v_head_dim), dt)}
     if kind in (ATTN, SWA, LOCAL):
         L = _cache_len(kind, cfg, ctx_len)
         return {"k": jnp.zeros((B, L, KH, Dh), dt),
@@ -485,12 +631,35 @@ def init_cache(cfg: ArchConfig, B: int, ctx_len: int):
     for i in range(cfg.n_tail_layers):
         kind = cfg.block_pattern[i % cfg.pattern_period]
         tail.append(_init_layer_state(kind, cfg, B, ctx_len))
-    return {"blocks": blocks, "tail": tail}
+    cache = {"blocks": blocks, "tail": tail}
+    if cfg.first_k_dense:
+        cache["lead"] = [_init_layer_state(cfg.block_pattern[0], cfg, B,
+                                           ctx_len)
+                         for _ in range(cfg.first_k_dense)]
+    return cache
+
+
+def _mla_decode(x, p, cfg, cache, pos):
+    """One-token latent attention against a cache of per-head k and v."""
+    with jax.named_scope("mla"):
+        h = rms_norm(x, p["ln"], cfg.norm_eps)
+        B = h.shape[0]
+        q, k, v = _mla_qkv(h, p, cfg, jnp.full((1,), pos))
+        ck = lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
+                                      (0, pos, 0, 0))
+        cv = lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
+                                      (0, pos, 0, 0))
+        o = attention(q, ck.astype(q.dtype), cv.astype(q.dtype), causal=False,
+                      kv_len=pos + 1)
+        o = dense(o.reshape(B, 1, cfg.n_heads * cfg.v_head_dim), p["wo"])
+    return x + o, {"k": ck, "v": cv}
 
 
 def _decode_layer(kind, x, layer, state, cfg, pos, ctx):
     """One-token step for one layer. Returns (x, new_state)."""
-    if kind in (ATTN, SWA, LOCAL):
+    if kind == MLA:
+        x, new = _mla_decode(x, layer["mix"], cfg, state, pos)
+    elif kind in (ATTN, SWA, LOCAL):
         w = _window_for(kind, cfg)
         x, new = decode_attention_block(x, layer["mix"], cfg, state, pos,
                                         window=w)
@@ -513,7 +682,7 @@ def _decode_layer(kind, x, layer, state, cfg, pos, ctx):
     else:
         raise ValueError(kind)
     if "ffn" in layer:
-        x, _ = _apply_ffn(x, layer["ffn"], cfg, ctx)
+        x, _, _ = _apply_ffn(x, layer["ffn"], cfg, ctx)
     return x, new
 
 
@@ -545,6 +714,12 @@ def decode_step(params, cfg: ArchConfig, tokens, pos, cache, *,
         return x, new_states
 
     new_cache = {"blocks": cache["blocks"], "tail": []}
+    if cfg.first_k_dense:
+        new_cache["lead"] = []
+        for layer, st in zip(params["lead"], cache["lead"]):
+            x, ns = _decode_layer(cfg.block_pattern[0], x, layer, st, cfg,
+                                  pos, ctx)
+            new_cache["lead"].append(ns)
     if cfg.n_scan_blocks:
         x, new_blocks = lax.scan(period_body, x,
                                  (params["blocks"], cache["blocks"]))
@@ -574,8 +749,8 @@ def prefill(params, cfg: ArchConfig, batch, *, kernel_mode="reference",
                    positions=jnp.arange(S), memory=memory, mesh=mesh)
     x = shard_hint(x, ctx, ("batch", None, None))
     # full-seq forward collecting per-layer KV
-    x, _, (kvs, tail_kvs) = _stack_forward(params, cfg, x, ctx,
-                                           collect_kv=True)
+    x, _, (kvs, tail_kvs, lead_kvs), _ = _stack_forward(params, cfg, x, ctx,
+                                                        collect_kv=True)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps)
     head = _head_matrix(params, cfg)
     logits = jnp.einsum("bd,dv->bv", x[:, -1], head.astype(x.dtype))
@@ -584,7 +759,7 @@ def prefill(params, cfg: ArchConfig, batch, *, kernel_mode="reference",
     dt = jnp.dtype(cfg.dtype)
 
     def to_cache(kind, kv):
-        if kind not in (ATTN, SWA, LOCAL, CROSS):
+        if kind not in (ATTN, SWA, LOCAL, CROSS, MLA):
             return kv                       # recurrent state dict, verbatim
         k, v = kv  # (B,S,KH,Dh) [or (n,B,S,KH,Dh) when scanned], or memory KV
         if kind == CROSS:
@@ -614,4 +789,6 @@ def prefill(params, cfg: ArchConfig, batch, *, kernel_mode="reference",
         kind = cfg.block_pattern[i % cfg.pattern_period]
         if i < len(tail_kvs):
             cache["tail"][i] = to_cache(kind, tail_kvs[i])
+    for i, kv in enumerate(lead_kvs):
+        cache["lead"][i] = to_cache(cfg.block_pattern[0], kv)
     return logits, cache
