@@ -60,9 +60,10 @@ def sizes_of(config: str) -> tuple:
 def arch(frozen_sizes: tuple):
     from repro.models.config import MLA, ArchConfig
     s = dict(frozen_sizes)
-    if s["scoring_func"] != "sigmoid":
-        raise ValueError("the held-share layer routes by sigmoid scores; "
-                         f"the file says {s['scoring_func']!r}")
+    if s["scoring_func"] != "sigmoid" or not s["norm_topk_prob"]:
+        raise ValueError("the held-share layer routes by sigmoid scores "
+                         "and normalises its top-k gates; the file says "
+                         f"{s['scoring_func']!r}, {s['norm_topk_prob']!r}")
     return ArchConfig(
         name="moonlight-16b-a3b", family="moe",
         n_layers=s["num_hidden_layers"], d_model=s["hidden_size"],
@@ -77,7 +78,7 @@ def arch(frozen_sizes: tuple):
         n_shared_experts=s["n_shared_experts"],
         first_k_dense=s["first_k_dense_replace"],
         routed_scale=s["routed_scaling_factor"],
-        norm_topk=s["norm_topk_prob"], balance_alpha=s["balance_loss_alpha"],
+        balance_alpha=s["balance_loss_alpha"],
         tie_embeddings=False, norm_eps=s["rms_norm_eps"],
         rope_theta=float(s["rope_theta"]))
 
@@ -173,9 +174,10 @@ def _text(compiled) -> str:
 
 def hlo_text(module: str):
     """The optimized HLO text of a program compiled in this process, or
-    None: not compiled, or its executable (one loaded from the persistent
-    compilation cache, say) gives no text.  Metric readers call this after
-    the window, where an error would cost the run its result line."""
+    None: not compiled, or its executable gives no text (one served from
+    the persistent compilation cache gives it, on the TPU as on the CPU).
+    Metric readers call this after the window, where an error would cost
+    the run its result line."""
     compiled = COMPILED.get(module)
     if compiled is None:
         return None

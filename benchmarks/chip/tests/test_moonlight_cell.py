@@ -85,6 +85,14 @@ def _biased_gates(route):
     return wrong
 
 
+def _unnormalised_gates(route):
+    def wrong(h, p, cfg):
+        scores, idx, _ = route(h, p, cfg)
+        return scores, idx, cfg.routed_scale * jnp.take_along_axis(
+            scores, idx, axis=-1)
+    return wrong
+
+
 def _arch_with(tools, **change):
     arch = tools.arch
 
@@ -104,7 +112,8 @@ def test_planted_fault_is_not_correct(root, monkeypatch, fresh_programs,
         monkeypatch.setattr(moe, "route_sigmoid",
                             _biased_gates(moe.route_sigmoid))
     elif fault == "no_topk_normalisation":
-        monkeypatch.setattr(tools, "arch", _arch_with(tools, norm_topk=False))
+        monkeypatch.setattr(moe, "route_sigmoid",
+                            _unnormalised_gates(moe.route_sigmoid))
     elif fault == "scale_missing":
         monkeypatch.setattr(tools, "arch", _arch_with(tools, routed_scale=1.0))
     elif fault == "shared_expert_left_out":

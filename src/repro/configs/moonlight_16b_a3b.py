@@ -32,7 +32,6 @@ CONFIG = ArchConfig(
     n_shared_experts=2,
     first_k_dense=1,
     routed_scale=2.446,
-    norm_topk=True,
     balance_alpha=1e-4,
     rope_theta=50_000.0,
     norm_eps=1e-5,

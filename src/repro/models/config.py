@@ -56,8 +56,7 @@ class ArchConfig:
     moe_d_ff: int = 0                # expert width (0: d_ff)
     n_shared_experts: int = 0        # shared SwiGLU of n_shared * moe_d_ff
     first_k_dense: int = 0           # leading layers with a d_ff SwiGLU
-    routed_scale: float = 1.0        # gates times this
-    norm_topk: bool = True           # gates normalised over the top_k
+    routed_scale: float = 1.0        # top-k-normalised gates times this
     balance_alpha: float = 0.0       # sequence-wise balance loss weight
     # Latent attention (MLA): kv_lora_rank > 0 with block kind MLA.
     kv_lora_rank: int = 0

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 
 _QBLOCK = 512          # query-block rows for blockwise attention
-_PLAIN_ATTN_MAX = 2048  # below this seq-len, plain attention is fine
+_PLAIN_ATTN_MAX = _QBLOCK  # up to one block, plain attention
 
 
 # ---------------------------------------------------------------------------

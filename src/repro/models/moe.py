@@ -12,8 +12,8 @@ Three dispatch strategies:
   ``experts_held`` > 0: sigmoid router scores over all ``n_experts`` with a
   selection bias, gates normalised over the top-k and scaled, a shared
   expert, and a dropless grouped matmul (``jax.lax.ragged_dot``) over only
-  the experts this chip holds.  It is one chip's share of an
-  expert-parallel layer, run without its exchange.
+  the experts this chip holds, one sequence at a time.  It is one chip's
+  share of an expert-parallel layer, run without its exchange.
 
 The first two apply softmax top-k routing with a Switch load-balancing loss
 and drop assignments past a capacity.  Their experts are tensor-parallel on
@@ -198,8 +198,7 @@ def route_sigmoid(h, p, cfg):
     scores = jax.nn.sigmoid(logits)
     _, idx = lax.top_k(scores + lax.stop_gradient(p["bias"]), cfg.top_k)
     sel = jnp.take_along_axis(scores, idx, axis=-1)
-    if cfg.norm_topk:
-        sel = sel / jnp.sum(sel, axis=-1, keepdims=True)
+    sel = sel / jnp.sum(sel, axis=-1, keepdims=True)
     return scores, idx, cfg.routed_scale * sel
 
 
@@ -216,25 +215,19 @@ def sequence_balance_loss(scores, idx, B: int, S: int, cfg):
     return cfg.balance_alpha * jnp.mean(jnp.sum(f * P, axis=-1))
 
 
-def moe_held_block(x, p, cfg):
-    """Pre-norm DeepSeekMoE residual block on the held experts. x: (B,S,d).
+def _held_share(h, idx, gates, w, cfg):
+    """One sequence's tokens through the held experts, dropless.
 
-    Returns (x, balance_loss, stats): ``stats`` holds the assignments
-    computed per held expert (``expert_load``, (experts_held,)), the held
-    assignments not computed (``dropped``, 0 by construction) and each
-    token's selected experts (``routing``, (B, S, k))."""
-    B, S, d = x.shape
-    T, k, Hn = B * S, cfg.top_k, cfg.experts_held
-    h = rms_norm(x, p["ln"], cfg.norm_eps).reshape(T, d)
-    with jax.named_scope("moe_route"):
-        scores, idx, gates = route_sigmoid(h, p, cfg)
-        aux = sequence_balance_loss(scores, idx, B, S, cfg)
+    h: (t, d); idx, gates: (t, k); w: the held experts' w1, w3, w2.
+    Returns (y (t, d) f32, load (experts_held,) int32, dropped int32)."""
+    t, d = h.shape
+    k, Hn = cfg.top_k, cfg.experts_held
     with jax.named_scope("moe_dispatch"):
         local = idx.reshape(-1) - cfg.expert_offset
         held = (local >= 0) & (local < Hn)
         slot = jnp.where(held, local, Hn)            # Hn: not held here
         order = jnp.argsort(slot, stable=True)       # held first, by expert
-        M = held_rows(T, cfg)
+        M = held_rows(t, cfg)
         rows = order[:M]
         load = jnp.sum(jax.nn.one_hot(slot, Hn + 1, dtype=jnp.int32),
                        axis=0)[:Hn]
@@ -249,18 +242,44 @@ def moe_held_block(x, p, cfg):
         xs = jnp.where(valid[:, None], h[tok], 0)
         gw = jnp.where(valid, gates.reshape(-1)[rows], 0.0)
     with jax.named_scope("moe_experts"):
-        def grouped(x, w):
-            y = lax.ragged_dot(x, w.astype(h.dtype), sizes)
+        def grouped(x, wi):
+            y = lax.ragged_dot(x, wi.astype(h.dtype), sizes)
             return jnp.where(valid[:, None], y, 0)
-        a, b = grouped(xs, p["w1"]), grouped(xs, p["w3"])
-        ys = grouped(act_fn(cfg.act)(a) * b, p["w2"])
+        a, b = grouped(xs, w["w1"]), grouped(xs, w["w3"])
+        ys = grouped(act_fn(cfg.act)(a) * b, w["w2"])
     with jax.named_scope("moe_combine"):
-        y = jnp.zeros((T, d), jnp.float32).at[tok].add(
+        y = jnp.zeros((t, d), jnp.float32).at[tok].add(
             ys.astype(jnp.float32) * gw[:, None])
+    return y, load, jnp.maximum(n_held - M, 0).astype(jnp.int32)
+
+
+def moe_held_block(x, p, cfg):
+    """Pre-norm DeepSeekMoE residual block on the held experts. x: (B,S,d).
+
+    The held experts take one sequence at a time, each under its own
+    rematerialisation, so that the dropless buffer (``held_rows`` of a
+    sequence) and its gradient are one sequence's, not the batch's.
+
+    Returns (x, balance_loss, stats): ``stats`` holds the assignments
+    computed per held expert (``expert_load``, (experts_held,)), the held
+    assignments not computed (``dropped``, 0 by construction) and each
+    token's selected experts (``routing``, (B, S, k))."""
+    B, S, d = x.shape
+    T, k = B * S, cfg.top_k
+    h = rms_norm(x, p["ln"], cfg.norm_eps).reshape(T, d)
+    with jax.named_scope("moe_route"):
+        scores, idx, gates = route_sigmoid(h, p, cfg)
+        aux = sequence_balance_loss(scores, idx, B, S, cfg)
+    share = jax.checkpoint(lambda w, blk: _held_share(*blk, w, cfg))
+    experts = {n: p[n] for n in ("w1", "w3", "w2")}
+    _, (y, load, dropped) = lax.scan(
+        lambda c, blk: (c, share(experts, blk)), None,
+        (h.reshape(B, S, d), idx.reshape(B, S, k), gates.reshape(B, S, k)))
+    y = y.reshape(T, d)
     if "shared" in p:
         with jax.named_scope("moe_shared"):
             y = y + gated_mlp(h, p["shared"], cfg.act).astype(jnp.float32)
-    stats = {"expert_load": load,
-             "dropped": jnp.maximum(n_held - M, 0).astype(jnp.int32),
+    stats = {"expert_load": jnp.sum(load, axis=0),
+             "dropped": jnp.sum(dropped),
              "routing": idx.reshape(B, S, k).astype(jnp.int32)}
     return x + y.astype(x.dtype).reshape(B, S, d), aux, stats

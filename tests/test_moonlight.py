@@ -139,6 +139,32 @@ def test_mla_v_width_unlike_qk_matches_a_per_head_loop():
     assert rel(attention(q, k, v, causal=True), out) < 1e-5
 
 
+def test_attention_in_query_blocks_matches_one_softmax():
+    """Above one 512-row query block (the cell's 1,024 rows) attention runs
+    block by block under remat; values and gradients are those of one
+    causal softmax over the whole sequence, with v narrower than q/k."""
+    S, H, Dh, Dv = 1024, 2, 24, 8
+    ks = jax.random.split(jax.random.key(11), 3)
+    q = jax.random.normal(ks[0], (1, S, H, Dh))
+    k = jax.random.normal(ks[1], (1, S, H, Dh))
+    v = jax.random.normal(ks[2], (1, S, H, Dv))
+
+    def whole(q, k, v):
+        s = jnp.einsum("bqhd,bshd->bhqs", q, k) / math.sqrt(Dh)
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqs,bshd->bqhd", jax.nn.softmax(s, -1), v)
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v)))
+
+    blocked = lambda q, k, v: attention(q, k, v, causal=True)  # noqa: E731
+    assert rel(blocked(q, k, v), whole(q, k, v)) < 1e-5
+    got = jax.grad(loss(blocked), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(whole), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert rel(a, b) < 1e-5
+
+
 # ------------------------------------------------------------ MoE layer
 held_block = jax.jit(moe.moe_held_block, static_argnums=2)
 
