@@ -153,6 +153,10 @@ class DataManager:
         # bumped by drop_model: fences in-flight transfers so a copy that
         # lands after its site died can't register a stale replica
         self._model_epoch: Dict[str, int] = {}
+        # bumped by drop_model too: replicas only ever go away there, so
+        # what a caller learnt of token sizes and replicas holds while
+        # this reads the same
+        self.epoch = 0                             # lock: _lock
 
     def _rkey(self, token: str) -> str:
         """Remote-store key for a token (namespaced per run)."""
@@ -193,6 +197,7 @@ class DataManager:
             self.journal.drop_model(model)
         with self._lock:
             self._model_epoch[model] = self._model_epoch.get(model, 0) + 1
+            self.epoch += 1
             # purge the dedup map too: a consumer arriving after a redeploy
             # must trigger a fresh copy, not join a doomed pre-drop future
             for key in [k for k in self._inflight if k[1] == model]:

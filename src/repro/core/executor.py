@@ -33,8 +33,11 @@ journal does not depend on thread timing.  Used by
 
 Per iteration (both modes):
   1. fireable steps (all input tokens available) join the waiting queue;
-  2. each queued step resolves its binding (deepest path wins), lazily
-     deploys its model (R1), and asks the Scheduler for a resource;
+  2. each queued step resolves its binding (deepest path wins) once,
+     lazily deploys its model (R1), and asks the Scheduler for a
+     resource — a pass that placed nothing is not repeated until the
+     scheduler's state or the queue changes, so a tick's cost follows
+     what happened since the last one, not the queue's length;
   3. scheduled steps get their input tokens moved in by the DataManager
      (R4 elision / intra-model channel / R3 two-step) and run on a worker
      thread via the Connector;
@@ -56,6 +59,7 @@ from __future__ import annotations
 import os
 import threading
 import time
+from collections.abc import Mapping
 from concurrent.futures import ThreadPoolExecutor, Future
 from concurrent.futures import wait as futures_wait
 from dataclasses import dataclass, field
@@ -80,7 +84,7 @@ from repro.core.scheduler import (JobDescription, JobStatus, POLICIES,
                                   Scheduler)
 from repro.core.streamflow_file import Binding, StreamFlowConfig
 from repro.core.topology import TopologyGraph
-from repro.core.workflow import (InvocationPlan, Workflow,
+from repro.core.workflow import (InvocationPlan, ReadySet, Workflow,
                                  invocation_base, match_binding,
                                  parse_token_ref)
 
@@ -121,6 +125,36 @@ class RunResult:
         return [(e.step, e.resource, round(e.start - t0, 4),
                  round(e.end - t0, 4), e.status, e.attempt, e.speculative)
                 for e in sorted(self.events, key=lambda e: (e.start, e.seq))]
+
+
+@dataclass
+class _Queued:
+    """A fired invocation waiting for a resource: its binding and job
+    description, made once, at the first placement pass it waits in."""
+    binding: Binding
+    desc: JobDescription
+
+
+class _AvailView(Mapping):
+    """Scheduler job name -> the resources its waiting invocation's
+    binding offers, worked out when asked (``avail_of``, once per
+    binding): a free-only pass asks about the few jobs it probes, not
+    the whole queue."""
+
+    def __init__(self, queued: Dict[str, _Queued], ns: str,
+                 avail_of: Callable[[Binding], List[str]]):
+        self._queued, self._ns, self._avail_of = queued, ns, avail_of
+
+    def __getitem__(self, name: str) -> List[str]:
+        if not name.startswith(self._ns):
+            raise KeyError(name)
+        return self._avail_of(self._queued[name[len(self._ns):]].binding)
+
+    def __iter__(self):
+        return (self._ns + p for p in self._queued)
+
+    def __len__(self) -> int:
+        return len(self._queued)
 
 
 class _Command:
@@ -206,6 +240,16 @@ class StreamFlowExecutor:
                 else CacheConfig.from_value(cache))
         self.cache: Optional[InvocationCache] = cache
         self._memo_keys: Dict[str, str] = {}   # invocation path -> memo key
+        # the waiting queue's per-run state (reset by _run_plan): each
+        # waiting invocation's binding and description in queue order,
+        # those whose inputs may still need staging in, a count per
+        # binding, and what the last pass that placed nothing saw
+        self._queued: Dict[str, _Queued] = {}
+        self._unstaged: Dict[str, None] = {}
+        self._bound: Dict[int, list] = {}      # id(binding) -> [binding, n]
+        self._queue_version = 0
+        self._idle_at: Optional[Tuple[int, int]] = None
+        self._data_epoch = 0
         # topology: TopologyGraph | raw ``topology:`` block dict | None
         if isinstance(topology, dict):
             topology = (TopologyGraph.from_config(models, topology)
@@ -778,9 +822,17 @@ class StreamFlowExecutor:
             self._emit(WorkflowStarted(workflow=plan.name,
                                        invocations=len(plan.steps)))
 
-        done_tokens = set(inputs) | set(pre_tokens or ())
         completed: set = set(pre_completed or ())
+        # the tokens that exist, and the invocations they made fireable
+        done_tokens = ReadySet(plan, set(inputs) | set(pre_tokens or ()),
+                               started=completed)
         self._memo_keys.clear()                # per-execution scratch state
+        self._queued.clear()
+        self._unstaged.clear()
+        self._bound.clear()
+        self._queue_version = 0
+        self._idle_at = None
+        self._data_epoch = self.data.epoch
         self._wasted_seconds = 0.0
         self._wasted_invocations = 0
         running: Dict[str, dict] = {}          # step path -> job record
@@ -815,10 +867,8 @@ class StreamFlowExecutor:
                     #    whose memo entry verifies live is completed here
                     #    and never queues
                     with tracing.span("sf:fire"):
-                        started = (list(running) + list(completed) + waiting
-                                   + [r["path"] for r in retries])
                         memoed = 0
-                        for path in plan.fireable(done_tokens, started):
+                        for path in done_tokens.take():
                             if self.cache is not None and self._try_memo(
                                     plan, path, completed, done_tokens):
                                 memoed += 1
@@ -838,17 +888,16 @@ class StreamFlowExecutor:
                     for r in due:
                         self._retry(r["rec"], r["path"], running)
                     # 3. schedule the queue (whole-queue batch when pipelined)
-                    with tracing.span("sf:schedule"):
+                    with tracing.span("sf:schedule") as sp:
                         waiting = self._schedule_queue(
-                            plan, bindings, waiting, running, pool)
+                            plan, bindings, waiting, running, pool, sp)
                     # 3b. autoscaling: export the unplaced backlog as queue
                     #     pressure, then run one control iteration.  Entirely
                     #     absent without an autoscaler/service — the static
                     #     pool's scheduling is untouched.
                     if self._report_queue:
                         self.scheduler.note_queue(
-                            self._queue_entries(waiting, bindings),
-                            ns=self._ns)
+                            self._queue_entries(waiting), ns=self._ns)
                     if self.autoscaler is not None:
                         self.autoscaler.tick()
                     # 4. straggler speculation
@@ -860,15 +909,8 @@ class StreamFlowExecutor:
                             running, completed, done_tokens, failed_final,
                             retries, in_order=not self.pipelined)
                     # 6. grace-period undeploy (beyond-paper)
-                    pending = waiting + list(running) + [r["path"]
-                                                        for r in retries]
-                    pending_models = set()
-                    for p in pending:
-                        b = self._resolve_binding(p.split("#spec")[0],
-                                                  bindings)
-                        pending_models.update(m for m, _ in b.targets)
                     released = self.deployment.maybe_undeploy_idle(
-                        pending_models)
+                        self._pending_models(running, retries))
                     for m in released:
                         self.scheduler.forget_model(m)
                         self.data.drop_model(m)
@@ -1074,47 +1116,113 @@ class StreamFlowExecutor:
         """Scheduler job name back to the invocation path."""
         return job_name[len(self._ns):] if self._ns else job_name
 
-    def _queue_entries(self, waiting, bindings):
+    def _queue_entries(self, waiting):
         """The unplaced backlog as (job, service, candidate models)
         triples — the autoscaler's queue-pressure input."""
         entries = []
         for p in waiting:
-            b = self._resolve_binding(p, bindings)
+            b = self._queued[p].binding
             entries.append((self._sched_key(p), b.service,
                             [m for m, _ in b.targets]))
         return entries
 
-    def _schedule_queue(self, plan, bindings, waiting, running, pool):
+    def _pending_models(self, running, retries) -> set:
+        """Models that waiting, running or retrying work is bound to."""
+        bound = ([b for b, _n in self._bound.values()]
+                 + [r["binding"] for r in running.values()]
+                 + [r["rec"]["binding"] for r in retries])
+        return {m for b in bound for m, _ in b.targets}
+
+    def _avail_memo(self) -> Callable[[Binding], List[str]]:
+        """``_avail_for`` once per binding for the pass that asks: all
+        invocations of a step share their binding."""
+        memo: Dict[int, List[str]] = {}
+
+        def avail_of(b: Binding) -> List[str]:
+            got = memo.get(id(b))
+            if got is None:
+                got = memo[id(b)] = self._avail_for(b)
+            return got
+        return avail_of
+
+    def _schedule_queue(self, plan, bindings, waiting, running, pool, sp):
+        """One placement pass over the waiting queue, then (pipelined)
+        stage-in.  What it costs follows what changed since the last
+        tick: an invocation's binding and description are made once, and
+        availability once per binding; a pass that placed nothing is not
+        run again until the scheduler's version or the queue moves, since
+        it would place nothing again.
+        ``sp`` (the ``sf:schedule`` span) gets ``waiting``, ``probed``,
+        ``placed``, and ``skipped`` when the pass did not run."""
+        sp.set("waiting", len(waiting))
         if not waiting:
             return waiting
-        descs: Dict[str, JobDescription] = {}
-        avail: Dict[str, List[str]] = {}      # keyed by scheduler job name
-        for p in waiting:
+        if self.data.epoch != self._data_epoch:
+            # a site's replicas were dropped: sizes and staging change
+            self._data_epoch = self.data.epoch
+            for p, q in self._queued.items():
+                q.desc = self._job_desc(plan, p, q.binding.service)
+            self._unstaged = dict.fromkeys(self._queued)
+        # the queue is the invocations already known, then those fired
+        # since the last pass (fires append, placements remove from both)
+        for p in waiting[len(self._queued):]:
             b = self._resolve_binding(p, bindings)
-            descs[p] = self._job_desc(plan, p, b.service)
-            avail[self._sched_key(p)] = self._avail_for(b)
-        if not self.pipelined:
-            return self._schedule_serial(plan, bindings, waiting,
-                                         descs, avail, running, pool)
-        placed = self.scheduler.schedule_batch(
-            [descs[p] for p in waiting], avail, self.data.remote_paths)
-        placed_names = set()
-        for job, resource in placed:
-            path = self._strip_ns(job.name)
-            self._launch(plan, path,
-                         self._resolve_binding(path, bindings), resource,
-                         running, pool, attempt=0, speculative=False)
-            placed_names.add(path)
-        still = [p for p in waiting if p not in placed_names]
-        self._stage_in(plan, bindings, still,
-                       {self._strip_ns(k): v for k, v in avail.items()})
-        return still
+            self._queued[p] = _Queued(b, self._job_desc(plan, p, b.service))
+            self._unstaged[p] = None
+            self._bound.setdefault(id(b), [b, 0])[1] += 1
+            self._queue_version += 1
+        avail_of = self._avail_memo()
+        mark = (self.scheduler.version, self._queue_version)
+        # (the serialized loop reorders its queue on every pass: it runs
+        # each one)
+        if self.pipelined and mark == self._idle_at:
+            sp.set("skipped", True)
+        else:
+            # deploy and register what the pass may place on before the
+            # scheduler looks for free resources: bindings in the order
+            # they joined the queue, which is the order of their first
+            # waiting invocation wherever a deploy can still happen
+            for b, _n in self._bound.values():
+                avail_of(b)
+            queue = [self._queued[p].desc for p in waiting]
+            avail = _AvailView(self._queued, self._ns, avail_of)
+            if self.pipelined:
+                stats: Dict[str, int] = {}
+                placed = self.scheduler.schedule_batch(
+                    queue, avail, self.data.remote_paths, stats)
+                for job, resource in placed:
+                    self._place(plan, self._strip_ns(job.name), resource,
+                                running, pool)
+                probed, n_placed = stats["probed"], len(placed)
+                if placed:
+                    waiting = [p for p in waiting if p in self._queued]
+            else:
+                waiting, probed, n_placed = self._schedule_serial(
+                    plan, queue, avail, running, pool)
+            sp.set("probed", probed)
+            sp.set("placed", n_placed)
+            if not n_placed:
+                self._idle_at = mark
+        if self.pipelined:
+            self._stage_in(plan, avail_of)
+        return waiting
 
-    def _schedule_serial(self, plan, bindings, waiting, descs, avail,
-                         running, pool):
-        """The paper's loop: one Scheduler.schedule call per queued step."""
-        order = self.scheduler.order_queue(
-            [descs[p] for p in waiting], self.data.remote_paths)
+    def _place(self, plan, path, resource, running, pool):
+        """Launch a waiting invocation on the resource it was given."""
+        q = self._queued.pop(path)
+        self._unstaged.pop(path, None)
+        count = self._bound[id(q.binding)]
+        count[1] -= 1
+        if not count[1]:
+            del self._bound[id(q.binding)]
+        self._launch(plan, path, q.binding, resource, running, pool,
+                     attempt=0, speculative=False)
+
+    def _schedule_serial(self, plan, queue, avail, running, pool):
+        """The paper's loop: one Scheduler.schedule call per queued step.
+        Returns the unplaced paths in the order they were asked about, the
+        number asked and the number placed."""
+        order = self.scheduler.order_queue(queue, self.data.remote_paths)
         still = []
         for job in order:
             path = self._strip_ns(job.name)
@@ -1123,13 +1231,10 @@ class StreamFlowExecutor:
             if resource is None:
                 still.append(path)
                 continue
-            self._launch(plan, path, self._resolve_binding(path, bindings),
-                         resource, running, pool, attempt=0,
-                         speculative=False)
-        return still
+            self._place(plan, path, resource, running, pool)
+        return still, len(order), len(order) - len(still)
 
-    def _stage_in(self, plan, bindings, still: List[str],
-                  avail: Dict[str, List[str]]):
+    def _stage_in(self, plan, avail_of):
         """Prefetch inputs of slot-starved steps onto their bound site so the
         cross-site hop is already paid when a worker slot frees (the
         follow-up move is an intra-model copy or an R4 elision).
@@ -1139,19 +1244,23 @@ class StreamFlowExecutor:
         WAN hops worth prepaying beat the near-free LAN moves (which cost
         nothing at schedule time anyway).  Multi-target bindings stage
         toward the target the planner scores cheapest — the same argmin a
-        cost-weighted placement would pick."""
-        ranked: List[tuple] = []      # (-est_cost, queue_pos, path, tokens)
-        for pos, path in enumerate(still):
-            b = self._resolve_binding(path, bindings)
-            if not avail.get(path):
+        cost-weighted placement would pick.  An invocation found with
+        every input on one of its targets leaves the candidates: replicas
+        go away only with a site's drop, which puts it back."""
+        ranked: List[tuple] = []      # (-est_cost, queue_pos, path, ...)
+        for pos, path in enumerate(list(self._unstaged)):
+            b = self._queued[path].binding
+            avail = avail_of(b)
+            if not avail:
                 continue
             step = plan.steps[path]
             best = None               # (est, model, tokens)
             for model, _service in b.targets:
-                tokens, est = [], 0.0
+                tokens, est, held = [], 0.0, True
                 for t in step.inputs.values():
                     if self.data.has_replica(t, model):
                         continue
+                    held = False
                     # a token whose holder died has no source until the
                     # retry machinery recomputes it — don't spam the pool
                     # with copies doomed to fail
@@ -1160,16 +1269,20 @@ class StreamFlowExecutor:
                         continue
                     tokens.append(t)
                     est += self.data.estimate_cost(t, model)
+                if held:
+                    del self._unstaged[path]
+                    best = None
+                    break
                 if best is None or est < best[0]:
                     best = (est, model, tokens)
             if best and best[2] and best[0] > 0:
-                ranked.append((-best[0], pos, path, best[1], best[2]))
+                ranked.append((-best[0], pos, path, best[1], best[2], avail))
         ranked.sort(key=lambda r: r[:2])
-        for _, _, path, model, tokens in ranked[:self.prefetch_depth]:
+        for _, _, path, model, tokens, avail in ranked[:self.prefetch_depth]:
             # the exact resource doesn't matter: once any replica is on the
             # site, the schedule-time move is an intra-model copy (LAN) or
             # an R4 elision — the WAN hop is what stage-in prepays
-            targets = [r for r in avail[path]
+            targets = [r for r in avail
                        if self._placement_of_model(r) == model]
             if not targets:
                 continue

@@ -142,6 +142,11 @@ def _loc_model(loc, resources: Dict[str, "ResourceAllocation"]
 
 
 class Policy(abc.ABC):
+    #: the policy places a job only on a resource that runs no job, so
+    #: that with no such resource among a job's candidates it answers
+    #: None without being asked (``Scheduler.schedule_batch`` stops there)
+    free_only: bool = False
+
     @abc.abstractmethod
     def get_resource(self, job_description: JobDescription,
                      available_resources: Sequence[str],
@@ -173,6 +178,7 @@ class DataLocalityPolicy(Policy):
     scores 0, so the paper's behaviour is the zero-cost special case.
     """
 
+    free_only = True
     topology = None                      # TopologyGraph | None
 
     def get_resource(self, job, available, remote_paths, jobs, resources):
@@ -191,6 +197,8 @@ class DataLocalityPolicy(Policy):
 
 
 class RoundRobinPolicy(Policy):
+    free_only = True
+
     def __init__(self):
         self._next = 0
         self._lock = threading.Lock()
@@ -381,6 +389,8 @@ class WidestFirstPolicy(Policy):
     queue wide — the classic critical-path heuristic for fork-join DAGs.
     Placement itself stays locality-aware."""
 
+    free_only = True
+
     def __init__(self):
         self.inner = DataLocalityPolicy()
 
@@ -404,6 +414,8 @@ class ScatterSpreadPolicy(Policy):
     the first one; placement *within* the chosen model stays
     data-locality (an inner :class:`DataLocalityPolicy`, cost-weighted
     when a topology is attached)."""
+
+    free_only = True
 
     def __init__(self):
         self.inner = DataLocalityPolicy()
@@ -449,7 +461,13 @@ class Scheduler:
     """Tracks allocations.  Answers one job at a time (``schedule``, the
     paper's FCFS contract) or a whole ready queue per tick
     (``schedule_batch``, the pipelined executor's contract) — queue-aware
-    policies see every fireable job before any placement is committed."""
+    policies see every fireable job before any placement is committed.
+
+    ``version`` counts the changes a placement can depend on: a commit, a
+    release (``notify``), a resource registered or forgotten, a drain flag
+    raised or cleared.  A caller whose queue has not changed since a pass
+    that placed nothing at version v places nothing again while the
+    version reads v, whichever run shares the scheduler."""
 
     def __init__(self, policy: Optional[Policy] = None, *, topology=None):
         self.policy = policy or DataLocalityPolicy()
@@ -461,6 +479,7 @@ class Scheduler:
         self._queued: Dict[str, Tuple[str, Tuple[str, ...]]] = {}  # lock: _lock
         # models with the drain flag up: placement skips their resources
         self._draining: set = set()                                # lock: _lock
+        self.version = 0                                           # lock: _lock
         self.topology = None
         if topology is not None:
             self.set_topology(topology)
@@ -481,12 +500,14 @@ class Scheduler:
             if name not in self.resources:
                 self.resources[name] = ResourceAllocation(
                     model, service, [], cores, memory_gb)
+                self.version += 1
 
     def forget_model(self, model: str):
         with self._lock:
             for name in [n for n, r in self.resources.items()
                          if r.model == model]:
                 del self.resources[name]
+                self.version += 1
 
     # -- autoscaler control surface (queue depth + drain flags) ---------------
     def note_queue(self, entries: Sequence[Tuple[str, str,
@@ -512,10 +533,13 @@ class Scheduler:
         """Raise/clear a model's drain flag: a draining model's resources
         take no new placements (retries and speculation included)."""
         with self._lock:
+            if draining == (model in self._draining):
+                return
             if draining:
                 self._draining.add(model)
             else:
                 self._draining.discard(model)
+            self.version += 1
 
     def is_draining(self, model: str) -> bool:
         with self._lock:
@@ -541,9 +565,15 @@ class Scheduler:
                 self.resources)
             if resource is None:
                 return None
+            self._commit(job, resource)
+            return resource
+
+    def _commit(self, job: JobDescription, resource: str):
+        """Record a placement."""
+        with self._lock:
             self.jobs[job.name] = JobAllocation(job, resource)
             self.resources[resource].jobs.append(job.name)
-            return resource
+            self.version += 1
 
     def order_queue(self, queue: List[JobDescription],
                     remote_paths: RemotePaths) -> List[JobDescription]:
@@ -555,7 +585,8 @@ class Scheduler:
 
     def schedule_batch(self, queue: Sequence[JobDescription],
                        available: Dict[str, Sequence[str]],
-                       remote_paths: RemotePaths
+                       remote_paths: RemotePaths,
+                       stats: Optional[Dict[str, int]] = None
                        ) -> List[Tuple[JobDescription, str]]:
         """Place as much of the ready queue as resources allow, atomically.
 
@@ -563,8 +594,12 @@ class Scheduler:
         policy with a ``select_batch`` hook sees the whole queue at once;
         otherwise jobs are placed one-by-one in (optionally reordered)
         queue order, each placement visible to the next ``get_resource``
-        call.  Returns committed (job, resource) pairs; unplaced jobs
-        simply stay in the executor's waiting queue."""
+        call.  A ``free_only`` policy is asked only about jobs with a free
+        candidate, and no further once no resource is free: it would
+        answer None.  Returns committed (job, resource) pairs; unplaced
+        jobs simply stay in the executor's waiting queue.  ``stats``, when
+        given, receives ``probed``: how many jobs the policy was asked
+        about."""
         with self._lock:
             if self._draining:
                 available = {name: self._usable(res)
@@ -573,25 +608,35 @@ class Scheduler:
             if select is not None:
                 picked = select(list(queue), available, remote_paths,
                                 self.jobs, self.resources)
+                probed = len(queue)
+                for job, resource in picked:
+                    self._commit(job, resource)
             else:
                 hook = getattr(self.policy, "order_queue", None)
                 ordered = (hook(list(queue), remote_paths, self.resources)
-                           if hook else list(queue))
-                picked = []
+                           if hook else queue)
+                free = ({n for n, r in self.resources.items() if not r.jobs}
+                        if getattr(self.policy, "free_only", False)
+                        else None)
+                picked, probed = [], 0
                 for job in ordered:
+                    cands = available.get(job.name, ())
+                    if free is not None:
+                        if not free:
+                            break
+                        if free.isdisjoint(cands):
+                            continue
+                    probed += 1
                     resource = self.policy.get_resource(
-                        job, available.get(job.name, ()), remote_paths,
-                        self.jobs, self.resources)
+                        job, cands, remote_paths, self.jobs, self.resources)
                     if resource is not None:
                         picked.append((job, resource))
                         # commit immediately so the next job sees it taken
-                        self.jobs[job.name] = JobAllocation(job, resource)
-                        self.resources[resource].jobs.append(job.name)
-                return picked
-            # commit select_batch's placements
-            for job, resource in picked:
-                self.jobs[job.name] = JobAllocation(job, resource)
-                self.resources[resource].jobs.append(job.name)
+                        self._commit(job, resource)
+                        if free is not None:
+                            free.discard(resource)
+            if stats is not None:
+                stats["probed"] = probed
             return picked
 
     def notify(self, job_name: str, status: JobStatus):
@@ -600,6 +645,7 @@ class Scheduler:
             if alloc is None:
                 return
             alloc.status = status
+            self.version += 1
             if status in (JobStatus.COMPLETED, JobStatus.FAILED):
                 res = self.resources.get(alloc.resource)
                 if res and job_name in res.jobs:

@@ -31,6 +31,10 @@ Span names (one place each, where the work happens):
 
 ``sf:tick``        one iteration of the engine loop, before its wait;
                    children ``sf:fire``, ``sf:schedule``, ``sf:harvest``
+``sf:schedule``    the tick's placement pass and stage-in; ``waiting``
+                   (the queue's length), ``probed`` (jobs the policy was
+                   asked about), ``placed``, ``skipped`` (the pass did not
+                   run: nothing it reads had changed)
 ``sf:work``        the worker function of one invocation
 ``sf:stage_in``    waiting for (or running) the invocation's transfers
 ``sf:body``        the step's own function

@@ -632,14 +632,54 @@ class InvocationPlan:
     def fireable(self, done_tokens: Sequence[str],
                  started: Sequence[str]) -> List[str]:
         """FCFS-ordered invocations whose input tokens all exist."""
-        have = set(done_tokens)
+        return ReadySet(self, done_tokens, started).take()
+
+
+class ReadySet:
+    """The invocations of a plan that became fireable, kept up to date as
+    tokens arrive rather than found by a scan of the plan.
+
+    Each invocation not yet started counts its input tokens that do not
+    exist yet; ``add`` of a token counts down its consumers, and one that
+    reaches zero is ready.  ``take`` hands the ready ones out in plan
+    (FCFS) order, each once.  ``add`` makes it stand where the set of
+    existing tokens stood."""
+
+    def __init__(self, plan: InvocationPlan, done_tokens: Sequence[str],
+                 started: Sequence[str] = ()):
+        self._order = {path: i for i, path in enumerate(plan.steps)}
+        self._have: set = set()
+        self._missing: Dict[str, int] = {}
+        self._consumers: Dict[str, List[str]] = {}
+        self._ready: List[str] = []
         busy = set(started)
-        out = []
-        for path, inv in self.steps.items():
+        for path, inv in plan.steps.items():
             if path in busy:
                 continue
-            if all(r in have for r in inv.inputs.values()):
-                out.append(path)
+            refs = set(inv.inputs.values())
+            self._missing[path] = len(refs)
+            for ref in refs:
+                self._consumers.setdefault(ref, []).append(path)
+            if not refs:
+                self._ready.append(path)
+        for token in done_tokens:
+            self.add(token)
+
+    def add(self, token: str):
+        """``token`` exists now."""
+        if token in self._have:
+            return
+        self._have.add(token)
+        for path in self._consumers.pop(token, ()):
+            self._missing[path] -= 1
+            if not self._missing[path]:
+                self._ready.append(path)
+
+    def take(self) -> List[str]:
+        """The invocations that became fireable since the last call, in
+        plan order."""
+        out, self._ready = self._ready, []
+        out.sort(key=self._order.__getitem__)
         return out
 
 
