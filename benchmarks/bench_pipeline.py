@@ -1,21 +1,17 @@
-"""Serialized FCFS vs the pipelined executor on the paper's topologies.
+"""The pipelined executor's makespan on the paper's topologies.
 
-The paper's loop (§4.4) schedules one task at a time and moves each task's
-tokens synchronously before it computes.  The pipelined executor schedules
-the whole ready queue per tick, issues transfers asynchronously (token
-movement for step N+1 overlaps compute of step N) and stages inputs onto
-slot-starved sites ahead of time.  This benchmark measures the makespan gap
-on:
+The executor schedules the whole ready queue per tick, issues transfers
+asynchronously (token movement for step N+1 overlaps compute of step N)
+and stages inputs onto slot-starved sites ahead of time.  This benchmark
+measures the makespan on:
 
-  fig8   — full-HPC (one shared-store site): the win comes from batch
-           scheduling + event-driven wakeup (no WAN hops to hide);
+  fig8   — full-HPC (one shared-store site): no WAN hops to hide;
   fig9   — hybrid HPC+cloud with NO shared data space and a simulated WAN
            link between the sites and the management node, with fewer cloud
-           slots than chains: the pipelined run hides the R3 two-step
-           copies behind compute, the serialized run pays them in-line.
+           slots than chains: the R3 two-step copies hide behind compute.
 
 Also compares the queue-aware policies (backfill / locality_batch /
-widest_first, beyond-paper) against plain data-locality in pipelined mode.
+widest_first, beyond-paper) against plain data-locality.
 """
 from __future__ import annotations
 
@@ -61,7 +57,7 @@ def _median(runs) -> dict:
 
 def _compare(doc_fn, *, repeats: int = 3, **variants) -> dict:
     """Interleave the variants' runs (A,B,A,B,...) so CPU-state drift over
-    the benchmark hits every mode equally; median-of-N per variant."""
+    the benchmark hits every variant equally; median-of-N per variant."""
     acc = {name: [] for name in variants}
     for _ in range(repeats):
         for name, kw in variants.items():
@@ -73,13 +69,11 @@ def run(verbose=True):
     warmup()
     rows = []
     for label, doc_fn in (("fig8", _fig8_doc), ("fig9", _fig9_doc)):
-        got = _compare(doc_fn,
-                       **{"serialized-fcfs": {"pipelined": False},
-                          "pipelined": {"pipelined": True}})
+        got = _compare(doc_fn, pipelined={})
         for mode, r in got.items():
             rows.append({"topology": label, "mode": mode, **r})
     queue = _compare(_fig9_doc, repeats=1,
-                     **{f"pipelined+{p}": {"pipelined": True, "policy": p}
+                     **{f"pipelined+{p}": {"policy": p}
                         for p in QUEUE_POLICIES[1:]})
     for mode, r in queue.items():
         rows.append({"topology": "fig9", "mode": mode, **r})
@@ -90,12 +84,6 @@ def run(verbose=True):
         print(" | ".join(f"{h:>18s}" for h in hdr))
         for r in rows:
             print(" | ".join(f"{str(r[h]):>18s}" for h in hdr))
-        fig9 = {r["mode"]: r for r in rows if r["topology"] == "fig9"}
-        s, p = fig9["serialized-fcfs"], fig9["pipelined"]
-        print(f"\n[claim] hybrid (Fig.9) pipelined makespan "
-              f"{p['makespan_s']:.3f}s vs serialized {s['makespan_s']:.3f}s "
-              f"({s['makespan_s'] / max(p['makespan_s'], 1e-9):.2f}x): "
-              f"transfers overlap compute instead of holding worker slots")
     return rows
 
 

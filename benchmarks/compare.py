@@ -3,8 +3,8 @@ committed ``benchmarks/baseline.json`` and FAIL on regression.
 
 Raw wall-clock numbers on shared CI runners are too noisy to gate on, so
 every gated metric is *self-normalizing* — a ratio between two variants
-measured in the same process (pipelined vs serialized makespan, direct vs
-two-step routing) or a deterministic structural count (bytes through the
+measured in the same process (direct vs two-step routing, pooled vs
+per-run service) or a deterministic structural count (bytes through the
 management node, number of direct transfers).  Each metric carries:
 
   * a committed baseline value (``benchmarks/baseline.json``),
@@ -40,15 +40,6 @@ def _rows_by(results: dict, bench: str, key: str) -> Dict[str, dict]:
         raise KeyError(f"bench.json has no results for {bench!r} "
                        f"(was it in --only?)")
     return {r[key]: r for r in rows}
-
-
-def _pipeline_speedup(results: dict) -> float:
-    """Serialized FCFS over pipelined makespan on the Fig.9 hybrid —
-    the PR-2 claim that pipelining hides the R3 transfer tax."""
-    fig9 = {r["mode"]: r for r in results["pipeline_makespan"]
-            if r.get("topology") == "fig9"}
-    return (fig9["serialized-fcfs"]["makespan_s"]
-            / max(fig9["pipelined"]["makespan_s"], 1e-9))
 
 
 def _recovery_speedup(results: dict) -> float:
@@ -237,8 +228,6 @@ class Metric:
 # transfers behind); the hard bounds carry the actual claims and never
 # loosen with the baseline.
 METRICS = [
-    Metric("pipeline_fig9_speedup", _pipeline_speedup,
-           higher_is_better=True, rel_tol=0.35, hard_min=1.0),
     # wall ratio: hard bound only in practice (rel_tol spans the quiet-
     # vs-contended-machine spread); the steps ratio is the tight check
     Metric("recovery_speedup", _recovery_speedup,

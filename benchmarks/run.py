@@ -62,8 +62,7 @@ def _sections():
     def pipeline():
         out, us = _timed(bench_pipeline.run)
         fig9 = {r["mode"]: r for r in out if r["topology"] == "fig9"}
-        return out, us, (f"serial={fig9['serialized-fcfs']['makespan_s']}s;"
-                         f"pipelined={fig9['pipelined']['makespan_s']}s")
+        return out, us, f"pipelined={fig9['pipelined']['makespan_s']}s"
 
     def recovery():
         out, us = _timed(bench_recovery.run)
@@ -135,8 +134,8 @@ def _sections():
          "strategies", deployment),
         ("fault_drills", "bench_fault — failure/straggler drills "
          "(beyond-paper)", fault),
-        ("pipeline_makespan", "bench_pipeline — serialized FCFS vs "
-         "pipelined executor", pipeline),
+        ("pipeline_makespan", "bench_pipeline — the pipelined executor "
+         "and the queue-aware policies", pipeline),
         ("recovery_makespan", "bench_recovery — journal crash-recovery vs "
          "from-scratch", recovery),
         ("routing_data_plane", "bench_routing — direct site-to-site "

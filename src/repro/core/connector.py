@@ -461,7 +461,7 @@ class Connector(abc.ABC):
         Config may declare a simulated WAN link between this site and the
         management node (``link_latency_s`` per copy + ``link_bandwidth_mbps``)
         so cross-site hops have real, measurable cost — this is what the
-        pipelined executor overlaps with compute.
+        executor overlaps with compute.
 
         ``REMOTE_TO_REMOTE`` with a ``peer`` connector is the *direct*
         cross-model channel (topology-routed transfers): the payload moves

@@ -22,8 +22,8 @@ Beyond-paper (flagged): the data plane is *async-first* —
 movement for step N+1 overlaps compute of step N, with in-flight
 transfers deduplicated per (token, destination): two consumers of one
 token trigger one physical copy, the second rides the first's Future.
-``transfer_sync`` runs the same single route implementation inline (the
-serialized executor's path).  With ``content_routing`` on (cache-enabled
+``transfer_sync`` runs the same single route implementation inline (a
+pool worker's body).  With ``content_routing`` on (cache-enabled
 runs), the planner adds a zero-cost *digest* route: when the destination
 store already holds the payload under any path, the transfer collapses
 to an index alias and the journal records it as elided-by-digest.
@@ -582,7 +582,7 @@ class DataManager:
         if raw is not None:
             self.journal.payload(token, raw)
 
-    # -- async transfer plane (pipelined executor) -------------------------------
+    # -- async transfer plane -------------------------------------------------
     def _pool(self) -> ThreadPoolExecutor:
         with self._lock:
             if self._xfer_pool is None:

@@ -10,28 +10,16 @@ Scheduler.  Scalar workflows expand to themselves (same paths, same
 token refs), so everything below reads the same for the paper's flat
 DAGs.
 
-Two dispatch modes share one loop body:
+The loop is event-driven and pipelined (beyond-paper; the paper's §4.4/§4.5
+loop is serialized).  Fireable invocations queue in FCFS order; each tick
+hands the *whole* queue to ``Scheduler.schedule_batch``, so queue-aware
+policies see every fireable step before any placement commits.  Input
+tokens of placed steps move asynchronously (per-token in-flight dedup),
+overlapping compute, and the inputs of slot-starved steps are *staged in*
+to their site ahead of time.  Completion callbacks wake the loop; retry
+backoff is deferred, never blocking dispatch of unrelated work.
 
-``pipelined=True`` (default, beyond-paper): an event-driven pipelined
-executor.  Per tick the *whole* ready queue is handed to the Scheduler
-(``schedule_batch``) so queue-aware policies (backfill, locality-batch,
-widest-first) see every fireable step before any placement commits; input
-tokens for placed steps move asynchronously through the DataManager
-(per-token in-flight dedup) so token movement for step N+1 overlaps compute
-of step N; steps that could not get a worker slot have their inputs
-*staged in* to the target site ahead of time, so the expensive cross-site
-hop is already paid when a slot frees.  Completion callbacks wake the loop
-instead of sleep-polling, and retry backoff is deferred (never blocks
-dispatch of unrelated work).
-
-``pipelined=False``: the paper's serialized FCFS loop (§4.4/§4.5), kept as
-the measured baseline — one ``Scheduler.schedule`` call per queued step,
-synchronous transfers inside the worker (the loop waits for them),
-sleep-polling, and one harvest a tick in launch order, so that a run's
-journal does not depend on thread timing.  Used by
-``benchmarks/bench_pipeline.py`` to quantify the pipelining win.
-
-Per iteration (both modes):
+Per iteration:
   1. fireable steps (all input tokens available) join the waiting queue;
   2. each queued step resolves its binding (deepest path wins) once,
      lazily deploys its model (R1), and asks the Scheduler for a
@@ -205,7 +193,6 @@ class StreamFlowExecutor:
                  grace_period_s: Optional[float] = None,
                  fault: Optional[FaultConfig] = None,
                  max_workers: int = 16,
-                 pipelined: bool = True,
                  transfer_workers: int = 8,
                  prefetch_depth: int = 8,
                  deadlock_timeout_s: float = 2.0,
@@ -318,7 +305,6 @@ class StreamFlowExecutor:
         self.fault = fault or FaultConfig()
         self.durations = DurationTracker()
         self.max_workers = max_workers
-        self.pipelined = pipelined
         self.prefetch_depth = prefetch_depth
         self.deadlock_timeout_s = deadlock_timeout_s
         self.events: List[JobEvent] = []
@@ -887,7 +873,7 @@ class StreamFlowExecutor:
                     retries = pending
                     for r in due:
                         self._retry(r["rec"], r["path"], running)
-                    # 3. schedule the queue (whole-queue batch when pipelined)
+                    # 3. schedule the queue (one whole-queue batch)
                     with tracing.span("sf:schedule") as sp:
                         waiting = self._schedule_queue(
                             plan, bindings, waiting, running, pool, sp)
@@ -907,7 +893,7 @@ class StreamFlowExecutor:
                     with tracing.span("sf:harvest"):
                         progressed = self._harvest(
                             running, completed, done_tokens, failed_final,
-                            retries, in_order=not self.pipelined)
+                            retries)
                     # 6. grace-period undeploy (beyond-paper)
                     released = self.deployment.maybe_undeploy_idle(
                         self._pending_models(running, retries))
@@ -916,10 +902,9 @@ class StreamFlowExecutor:
                         self.data.drop_model(m)
                         if self.cache is not None:
                             self.cache.drop_model(m)
-                # 7. progress bookkeeping: sleep on the wake event (pipelined)
-                #    or poll (serialized baseline); deadlock guard either way
-                #    (a memo hit is progress: its tokens may fire successors
-                #    immediately, so don't sleep on them)
+                # 7. progress bookkeeping: deadlock guard, then sleep on the
+                #    wake event (a memo hit is progress: its tokens may fire
+                #    successors immediately, so don't sleep on them)
                 if progressed or due or memoed:
                     starving_since = None
                     continue
@@ -938,16 +923,12 @@ class StreamFlowExecutor:
                                 f"no resources accept them")
                 else:
                     starving_since = None
-                if self.pipelined:
-                    timeout = 0.02
-                    if retries:
-                        soonest = min(r["retry_at"] for r in retries)
-                        timeout = min(timeout,
-                                      max(soonest - time.time(), 0.001))
-                    self._wake.wait(timeout)
-                    self._wake.clear()
-                else:
-                    time.sleep(0.003)
+                timeout = 0.02
+                if retries:
+                    soonest = min(r["retry_at"] for r in retries)
+                    timeout = min(timeout, max(soonest - time.time(), 0.001))
+                self._wake.wait(timeout)
+                self._wake.clear()
 
             # drain leftovers (surviving speculative twins / out-raced
             # primaries): their scheduler allocations and deployment job
@@ -1146,9 +1127,9 @@ class StreamFlowExecutor:
         return avail_of
 
     def _schedule_queue(self, plan, bindings, waiting, running, pool, sp):
-        """One placement pass over the waiting queue, then (pipelined)
-        stage-in.  What it costs follows what changed since the last
-        tick: an invocation's binding and description are made once, and
+        """One placement pass over the waiting queue, then stage-in.
+        What it costs follows what changed since the last tick: an
+        invocation's binding and description are made once, and
         availability once per binding; a pass that placed nothing is not
         run again until the scheduler's version or the queue moves, since
         it would place nothing again.
@@ -1173,9 +1154,7 @@ class StreamFlowExecutor:
             self._queue_version += 1
         avail_of = self._avail_memo()
         mark = (self.scheduler.version, self._queue_version)
-        # (the serialized loop reorders its queue on every pass: it runs
-        # each one)
-        if self.pipelined and mark == self._idle_at:
+        if mark == self._idle_at:
             sp.set("skipped", True)
         else:
             # deploy and register what the pass may place on before the
@@ -1186,25 +1165,19 @@ class StreamFlowExecutor:
                 avail_of(b)
             queue = [self._queued[p].desc for p in waiting]
             avail = _AvailView(self._queued, self._ns, avail_of)
-            if self.pipelined:
-                stats: Dict[str, int] = {}
-                placed = self.scheduler.schedule_batch(
-                    queue, avail, self.data.remote_paths, stats)
-                for job, resource in placed:
-                    self._place(plan, self._strip_ns(job.name), resource,
-                                running, pool)
-                probed, n_placed = stats["probed"], len(placed)
-                if placed:
-                    waiting = [p for p in waiting if p in self._queued]
+            stats: Dict[str, int] = {}
+            placed = self.scheduler.schedule_batch(
+                queue, avail, self.data.remote_paths, stats)
+            for job, resource in placed:
+                self._place(plan, self._strip_ns(job.name), resource,
+                            running, pool)
+            sp.set("probed", stats["probed"])
+            sp.set("placed", len(placed))
+            if placed:
+                waiting = [p for p in waiting if p in self._queued]
             else:
-                waiting, probed, n_placed = self._schedule_serial(
-                    plan, queue, avail, running, pool)
-            sp.set("probed", probed)
-            sp.set("placed", n_placed)
-            if not n_placed:
                 self._idle_at = mark
-        if self.pipelined:
-            self._stage_in(plan, avail_of)
+        self._stage_in(plan, avail_of)
         return waiting
 
     def _place(self, plan, path, resource, running, pool):
@@ -1217,22 +1190,6 @@ class StreamFlowExecutor:
             del self._bound[id(q.binding)]
         self._launch(plan, path, q.binding, resource, running, pool,
                      attempt=0, speculative=False)
-
-    def _schedule_serial(self, plan, queue, avail, running, pool):
-        """The paper's loop: one Scheduler.schedule call per queued step.
-        Returns the unplaced paths in the order they were asked about, the
-        number asked and the number placed."""
-        order = self.scheduler.order_queue(queue, self.data.remote_paths)
-        still = []
-        for job in order:
-            path = self._strip_ns(job.name)
-            resource = self.scheduler.schedule(job, avail[job.name],
-                                               self.data.remote_paths)
-            if resource is None:
-                still.append(path)
-                continue
-            self._place(plan, path, resource, running, pool)
-        return still, len(order), len(order) - len(still)
 
     def _stage_in(self, plan, avail_of):
         """Prefetch inputs of slot-starved steps onto their bound site so the
@@ -1309,35 +1266,21 @@ class StreamFlowExecutor:
         self.deployment.job_started(model)
         self._transition(path, "scheduled", model=model, resource=resource,
                          attempt=attempt, speculative=speculative)
-        tokens = list(step.inputs.values())
-        # pipelined: transfers start NOW, concurrent with other steps'
-        # compute; the worker only joins the futures
-        xfer_futs = (self.data.prefetch(tokens, model, resource)
-                     if self.pipelined else None)
-
-        # the serialized loop waits for the worker's stage-in: its running
-        # and transfer records then reach the journal in launch order
-        staged = None if self.pipelined else threading.Event()
+        # transfers start NOW, concurrent with other steps' compute; the
+        # worker only joins the futures
+        xfer_futs = self.data.prefetch(list(step.inputs.values()), model,
+                                       resource)
         # the worker's spans belong to this invocation (its JobEvent)
         inv = tracing.invocation(self._ns, key, attempt)
 
         def work():
             with tracing.attach(inv), tracing.span("sf:work"):
-                try:
-                    self._transition(path, "running", model=model,
-                                     resource=resource, attempt=attempt,
-                                     speculative=speculative)
-                    with tracing.span("sf:stage_in"):
-                        if xfer_futs is None:
-                            for token in tokens:    # serialized (R3/R4)
-                                self.data.transfer_sync(token, model,
-                                                        resource)
-                        else:
-                            for f in xfer_futs:
-                                f.result()  # propagate transfer failures
-                finally:
-                    if staged is not None:
-                        staged.set()
+                self._transition(path, "running", model=model,
+                                 resource=resource, attempt=attempt,
+                                 speculative=speculative)
+                with tracing.span("sf:stage_in"):
+                    for f in xfer_futs:
+                        f.result()  # propagate transfer failures
                 conn = self.deployment.get_connector(model)
                 cmd = _Command(step, self, model, resource)
                 conn.run(resource, cmd, environment={"__cancel__": cancel},
@@ -1347,37 +1290,13 @@ class StreamFlowExecutor:
         fut = pool.submit(work)
         rec["future"] = fut
         fut.add_done_callback(lambda _f: self._wake.set())
-        if staged is not None:
-            # (a future the pool cancels never runs the worker)
-            while not staged.wait(0.05) and not fut.done():
-                pass
 
     # ---------------------------------------------------------------- harvest
-    @staticmethod
-    def _next_in_order(running, completed) -> List[str]:
-        """The one invocation the serialized loop harvests this tick: the
-        oldest launched, once it has finished, so that what the loop sees
-        (and journals) does not depend on which worker finishes first.  A
-        speculative twin, and a primary whose twin already completed its
-        path, do not hold the others back; a finished twin is taken when
-        the oldest is still running."""
-        for key, rec in running.items():
-            if rec["future"].done():
-                return [key]
-            if not (rec["speculative"]
-                    or key.split("#spec")[0] in completed):
-                break
-        return [k for k, r in running.items()
-                if r["speculative"] and r["future"].done()][:1]
-
     def _harvest(self, running, completed, done_tokens, failed_final,
-                 retries: List[dict], *, in_order: bool = False) -> bool:
-        """Register finished invocations: every one that has finished,
-        or with ``in_order`` one a tick, in launch order (the serialized
-        loop)."""
+                 retries: List[dict]) -> bool:
+        """Register every invocation that has finished."""
         progressed = False
-        for key in (self._next_in_order(running, completed) if in_order
-                    else list(running)):
+        for key in list(running):
             rec = running[key]
             fut: Future = rec["future"]
             if not fut.done():

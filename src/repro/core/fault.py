@@ -3,10 +3,9 @@ straggler speculation (beyond-paper, DAGMan-style, but designed to fit the
 paper's FCFS loop: a speculative twin is just another job whose completion
 races the original's).
 
-Under the pipelined executor's concurrent dispatch, backoff is *deferred*
-rather than slept: the executor keeps a retry deadline per failed job and
-keeps dispatching unrelated ready work in the meantime, so one flaky site
-never stalls the whole queue."""
+Backoff is *deferred* rather than slept: the executor keeps a retry
+deadline per failed job and keeps dispatching unrelated ready work in the
+meantime, so one flaky site never stalls the whole queue."""
 from __future__ import annotations
 
 import threading

@@ -8,9 +8,9 @@ holding one; else any free resource; else None -> the task waits.
 
 Beyond-paper (flagged): queue-aware scheduling.  The paper notes such
 strategies "cannot currently be implemented" in its one-task-at-a-time FCFS
-loop; our pipelined executor hands policies the *whole* ready queue each
-tick via ``Scheduler.schedule_batch``.  Policies may implement two optional
-hooks on top of ``get_resource``:
+loop; our executor hands policies the *whole* ready queue each tick via
+``Scheduler.schedule_batch``.  Policies may implement two optional hooks on
+top of ``get_resource``:
 
   order_queue(queue, remote_paths, resources)   -> reordered queue
   select_batch(queue, available, remote_paths, jobs, resources)
@@ -289,7 +289,7 @@ class BackfillPolicy(Policy):
     who has none) *backfills* onto free resources nobody later in the queue
     has claimed as a locality target — so the queue head can't starve a
     later job of the one resource that would make its transfer free.
-    Exploits the pipelined executor's whole-queue scheduling mode."""
+    Exploits the executor's whole-queue scheduling."""
 
     def __init__(self):
         self.inner = DataLocalityPolicy()
@@ -458,10 +458,10 @@ POLICIES = {
 
 
 class Scheduler:
-    """Tracks allocations.  Answers one job at a time (``schedule``, the
-    paper's FCFS contract) or a whole ready queue per tick
-    (``schedule_batch``, the pipelined executor's contract) — queue-aware
-    policies see every fireable job before any placement is committed.
+    """Tracks allocations.  Places the whole ready queue per tick
+    (``schedule_batch``), so queue-aware policies see every fireable job
+    before any placement is committed; ``schedule`` places one job (a
+    retry or a speculative twin).
 
     ``version`` counts the changes a placement can depend on: a commit, a
     release (``notify``), a resource registered or forgotten, a drain flag
@@ -574,14 +574,6 @@ class Scheduler:
             self.jobs[job.name] = JobAllocation(job, resource)
             self.resources[resource].jobs.append(job.name)
             self.version += 1
-
-    def order_queue(self, queue: List[JobDescription],
-                    remote_paths: RemotePaths) -> List[JobDescription]:
-        hook = getattr(self.policy, "order_queue", None)
-        if hook is None:
-            return queue
-        with self._lock:
-            return hook(queue, remote_paths, self.resources)
 
     def schedule_batch(self, queue: Sequence[JobDescription],
                        available: Dict[str, Sequence[str]],

@@ -10,7 +10,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from benchmarks import compare  # noqa: E402
 
 
-def _bench(*, serial=1.0, piped=0.5, scratch=3.0, resumed=1.0,
+def _bench(*, scratch=3.0, resumed=1.0,
            scratch_steps=13, resumed_steps=10,
            mgmt_direct=100, mgmt_baseline=100_000, mk_direct=0.7,
            mk_mgmt=1.0, direct_n=8,
@@ -23,12 +23,6 @@ def _bench(*, serial=1.0, piped=0.5, scratch=3.0, resumed=1.0,
            mk_static=0.8, mk_elastic=0.26, wasted=2, useful=16,
            lb_ratio_unrolled=1.2, lb_ratio_scatter=1.8):
     return {"results": {
-        "pipeline_makespan": [
-            {"topology": "fig9", "mode": "serialized-fcfs",
-             "makespan_s": serial},
-            {"topology": "fig9", "mode": "pipelined", "makespan_s": piped},
-            {"topology": "fig8", "mode": "pipelined", "makespan_s": 9.9},
-        ],
         "recovery_makespan": [
             {"phase": "from-scratch", "makespan_s": scratch,
              "steps_executed": scratch_steps},
@@ -84,7 +78,6 @@ def _bench(*, serial=1.0, piped=0.5, scratch=3.0, resumed=1.0,
 
 def test_extract_metrics():
     m = compare.extract_metrics(_bench())
-    assert m["pipeline_fig9_speedup"] == pytest.approx(2.0)
     assert m["recovery_speedup"] == pytest.approx(3.0)
     assert m["recovery_steps_ratio"] == pytest.approx(10 / 13)
     assert m["routing_makespan_ratio"] == pytest.approx(0.7)
@@ -131,13 +124,22 @@ def test_gate_fails_on_mgmt_bytes_regression(tmp_path, capsys):
     assert "routing_mgmt_bytes_ratio" in out and "hard bound" in out
 
 
-def test_gate_fails_when_pipelining_stops_helping(tmp_path):
-    assert _run(tmp_path, _bench(piped=1.2)) == 1
+def test_gate_fails_on_metric_missing_from_baseline(tmp_path, capsys):
+    # a metric added without refreshing baseline.json fails loudly
+    bj = tmp_path / "bench.json"
+    bj.write_text(json.dumps(_bench()))
+    metrics = compare.extract_metrics(_bench())
+    del metrics["routing_direct_transfers"]
+    base = tmp_path / "baseline.json"
+    base.write_text(json.dumps({"metrics": metrics}))
+    assert compare.main([str(bj), "--baseline", str(base)]) == 1
+    out = capsys.readouterr().out
+    assert "routing_direct_transfers" in out and "no committed baseline" in out
 
 
 def test_gate_tolerates_noise_within_rel_tol(tmp_path):
     good = _bench()
-    noisy = _bench(piped=0.55, resumed=1.3, mk_direct=0.75)
+    noisy = _bench(resumed=1.3, mk_direct=0.75)
     assert _run(tmp_path, noisy, baseline_bench=good) == 0
 
 

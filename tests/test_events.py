@@ -1,6 +1,6 @@
-"""Typed event stream (PR 6 tentpole, part a): run_stream over both
-executor loops, backpressure without loss, resume replay, and
-serialized-vs-pipelined event equivalence."""
+"""Typed event stream: run_stream's terminal state, backpressure without
+loss, resume replay, and each invocation's lifecycle."""
+import json
 import random
 import sys
 import threading
@@ -14,8 +14,9 @@ from repro.configs.paper_pipeline import build_workflow as build_scalar
 from repro.core import (CheckpointConfig, EventSink, FaultConfig,
                         InvocationStateChanged, ModelSpec, RunCancelled,
                         StreamFlowExecutor, TokenAvailable, TransferRouted,
-                        WorkflowCompleted, WorkflowStarted)
+                        WorkflowCompleted, WorkflowStarted, serialize)
 from repro.core.streamflow_file import Binding
+from repro.core.workflow import parse_token_ref
 
 SITE = {"site": ModelSpec("site", "local",
                           {"services": {"svc": {"replicas": 4}}})}
@@ -42,14 +43,12 @@ BUILDERS = {
 # ------------------------------------------------------- terminal equality
 
 @pytest.mark.parametrize("name", sorted(BUILDERS))
-@pytest.mark.parametrize("pipelined", [True, False],
-                         ids=["pipelined", "serialized"])
-def test_stream_terminal_state_equals_run_result(name, pipelined):
+def test_stream_terminal_state_equals_run_result(name):
     wf = BUILDERS[name]()
-    ref = _executor(pipelined=pipelined).run(wf, BIND, {"seed": 7})
+    ref = _executor().run(wf, BIND, {"seed": 7})
 
     wf2 = BUILDERS[name]()
-    es = _executor(pipelined=pipelined).run_stream(wf2, BIND, {"seed": 7})
+    es = _executor().run_stream(wf2, BIND, {"seed": 7})
     events = list(es)
 
     assert isinstance(events[0], WorkflowStarted)
@@ -179,31 +178,48 @@ def test_unconsumed_stream_still_completes():
     assert "combined" in es.result(timeout=30).outputs
 
 
-# ------------------------------------- serialized/pipelined equivalence
+# ------------------------------------------------------------- lifecycles
 
-def _state_multiset(events):
-    """Ordering-normalized view of the invocation lifecycle: the multiset
-    of (path, state) transitions, speculative twins excluded."""
-    pairs = [(e.path, e.state) for e in events
-             if isinstance(e, InvocationStateChanged)
-             and not e.speculative]
-    return sorted(pairs)
+@pytest.mark.parametrize("name", ["scalar", "diamond", "scatter"])
+def test_invocation_lifecycles_and_token_announcements(name):
+    """Every invocation of the plan goes fireable -> scheduled -> running
+    -> completed, and every token of the plan is announced once, with its
+    tag (scatter shards keep their identity)."""
+    wf = BUILDERS[name]()
+    plan = wf.expand()
+    events = list(_executor().run_stream(wf, BIND, {"seed": 6}))
+    lifecycles = {}
+    for e in events:
+        if isinstance(e, InvocationStateChanged) and not e.speculative:
+            lifecycles.setdefault(e.path, []).append(e.state)
+    assert lifecycles == {
+        path: ["fireable", "scheduled", "running", "completed"]
+        for path in plan.steps}
+    announced = sorted((e.token, e.port, tuple(e.tag)) for e in events
+                       if isinstance(e, TokenAvailable))
+    expected = sorted((t, *parse_token_ref(t))
+                      for inv in plan.steps.values() for t in inv.outputs)
+    assert announced == expected
 
 
 @pytest.mark.parametrize("name", ["diamond", "scatter"])
-def test_serialized_and_pipelined_emit_identical_lifecycles(name):
-    streams = {}
-    for pipelined in (True, False):
-        es = _executor(pipelined=pipelined).run_stream(
-            BUILDERS[name](), BIND, {"seed": 6})
-        streams[pipelined] = list(es)
-    assert _state_multiset(streams[True]) == _state_multiset(streams[False])
-    # token stories agree too (tags included — scatter shards keep identity)
-    for key in [True, False]:
-        streams[key] = sorted((e.token, e.port, tuple(e.tag))
-                              for e in streams[key]
-                              if isinstance(e, TokenAvailable))
-    assert streams[True] == streams[False]
+def test_journal_step_records_repeat_across_runs(name, tmp_path):
+    """Two runs of one workflow journal the same multiset of (path, state)
+    step records and give the same outputs, whichever worker finishes
+    first."""
+    runs = []
+    for i in range(2):
+        journal = tmp_path / f"run{i}.jsonl"
+        res = _executor(checkpoint=CheckpointConfig(
+            journal_path=str(journal))).run(BUILDERS[name](), BIND,
+                                            {"seed": 6})
+        with open(journal) as f:
+            recs = [json.loads(line) for line in f]
+        steps = sorted((r["path"], r["state"]) for r in recs
+                       if r["kind"] == "step")
+        runs.append((steps, serialize(res.outputs)))
+    assert runs[0] == runs[1]
+    assert runs[0][0]                   # the journal did record the steps
 
 
 # ------------------------------------------------------------ resume replay

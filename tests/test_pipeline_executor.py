@@ -1,5 +1,5 @@
 """Pipelined executor: async prefetch, in-flight dedup, queue-aware batch
-scheduling, and serialized-vs-pipelined equivalence."""
+scheduling, and outputs under concurrent completions."""
 import time
 
 import pytest
@@ -31,9 +31,8 @@ def _slow_link_site(replicas=1, latency=0.15):
         "services": {"svc": {"replicas": replicas}}})}
 
 
-def _run(pipelined, n=3, sleep=0.2, replicas=1):
+def _run(n=3, sleep=0.2, replicas=1):
     ex = StreamFlowExecutor(_slow_link_site(replicas=replicas),
-                            pipelined=pipelined,
                             fault=FaultConfig(speculative=False))
     wf = _wf_independent(n, sleep)
     res = ex.run(wf, [Binding("/", "site", "svc")],
@@ -41,45 +40,32 @@ def _run(pipelined, n=3, sleep=0.2, replicas=1):
     return ex, res
 
 
-def test_pipelined_and_serialized_agree_on_outputs():
-    _, rs = _run(pipelined=False, n=3, sleep=0.0)
-    _, rp = _run(pipelined=True, n=3, sleep=0.0)
-    assert rs.outputs == rp.outputs == {f"out{i}": i for i in range(3)}
-    for res in (rs, rp):
-        done = [e for e in res.events if e.status == "completed"]
-        assert len(done) == 3
+@pytest.mark.parametrize("replicas", [1, 2])
+def test_outputs_under_concurrent_completions(replicas):
+    # the outputs are the known values whichever order workers finish in:
+    # two replicas let completions race
+    _, res = _run(n=3, sleep=0.0, replicas=replicas)
+    assert res.outputs == {f"out{i}": i for i in range(3)}
+    done = [e for e in res.events if e.status == "completed"]
+    assert sorted(e.step for e in done) == ["/j0", "/j1", "/j2"]
 
 
 def test_pipelined_overlaps_transfers_with_compute():
-    # one worker slot, 3 jobs, 150ms WAN hop per input token:
-    # serialized pays (hop + compute) per job in-line; pipelined stages
-    # token N+1 in while job N computes
-    _, rs = _run(pipelined=False)
-    _, rp = _run(pipelined=True)
-    assert rs.outputs == rp.outputs
-    # serialized lower bound: 3 * (0.15 + 0.2); pipelined hides 2 hops
-    assert rp.wall_seconds < rs.wall_seconds - 0.1
-
-
-def test_serialized_loop_harvests_in_launch_order():
-    """Two replicas, the first-launched invocation the slower: the
-    serialized loop harvests it first all the same, so what it journals
-    does not depend on which worker finishes first."""
-    wf = Workflow("order")
-    wf.add_step(Step("/slow", lambda i, c: time.sleep(0.2) or {"a": 1},
-                     {}, ("a",)))
-    wf.add_step(Step("/fast", lambda i, c: {"b": 2}, {}, ("b",)))
-    ex = StreamFlowExecutor(_slow_link_site(replicas=2, latency=0.0),
-                            pipelined=False,
-                            fault=FaultConfig(speculative=False))
-    res = ex.run(wf, [Binding("/", "site", "svc")], {})
-    ev = {e.step: e for e in res.events if e.status == "completed"}
-    assert ev["/slow"].start <= ev["/fast"].start
-    assert ev["/slow"].end <= ev["/fast"].end
+    # one worker slot, 3 jobs, 150ms WAN hop per input token: a loop that
+    # moved each job's token in-line would pay (hop + compute) per job;
+    # the pipelined loop stages token N+1 in while job N computes
+    _, res = _run()
+    assert res.outputs == {f"out{i}": i for i in range(3)}
+    # from the first job's start to the last one's end (the run's wall
+    # time adds deployment and collecting the outputs): under the in-line
+    # lower bound 3 * (0.15 + 0.2) by more than 0.1 s, 2 of 3 hops hidden
+    jobs = [e for e in res.events if e.status == "completed"]
+    span = max(e.end for e in jobs) - min(e.start for e in jobs)
+    assert span < 3 * (0.15 + 0.2) - 0.1
 
 
 def test_stage_in_prefetches_before_slot_frees():
-    ex, res = _run(pipelined=True)
+    ex, res = _run()
     rows = res.timeline_rows()
     # with prefetch, later jobs start back-to-back: the gap between a job's
     # end and the next job's start stays well under one 150ms WAN hop

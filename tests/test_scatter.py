@@ -161,10 +161,8 @@ def _pool(n=4):
                            {"services": {"s": {"replicas": n}}})}
 
 
-@pytest.mark.parametrize("pipelined", [True, False])
-def test_scatter_gather_runs_in_both_modes(pipelined):
-    ex = StreamFlowExecutor(_pool(), pipelined=pipelined,
-                            fault=FaultConfig(speculative=False))
+def test_scatter_gather_runs():
+    ex = StreamFlowExecutor(_pool(), fault=FaultConfig(speculative=False))
     res = ex.run(_scatter_wf(3), [Binding("/", "m", "s")], {"seed": 0})
     assert res.outputs["total"] == 100 + 400 + 900
     done = [e.step for e in res.events if e.status == "completed"]
@@ -243,17 +241,16 @@ def test_scatter_spread_policy_balances_groups():
 # ---------------------------------------------------- the paper pipeline, wide
 
 @pytest.mark.slow
-@pytest.mark.parametrize("pipelined", [True, False])
-def test_paper_pipeline_scatter_32_samples_end_to_end(pipelined):
-    """Acceptance: the §5 pipeline via ``scatter:`` over 32 samples runs in
-    both modes and spreads invocations across both sites."""
+def test_paper_pipeline_scatter_32_samples_end_to_end():
+    """Acceptance: the §5 pipeline via ``scatter:`` over 32 samples runs
+    and spreads invocations across both sites."""
     doc = streamflow_doc_scatter_hybrid(n_samples=32, hpc_replicas=6,
                                         cloud_replicas=6, **SCATTER_WF_ARGS)
     cfg = load_streamflow_file(doc)
     entry = cfg.workflows["single-cell"]
     assert entry.workflow.expand().scatter_widths() == {
         "/count": 32, "/seurat": 32, "/singler": 32}
-    ex = StreamFlowExecutor.from_config(cfg, pipelined=pipelined,
+    ex = StreamFlowExecutor.from_config(cfg,
                                         fault=FaultConfig(speculative=False))
     res = ex.run(entry.workflow, entry.bindings, inputs={"seed": 0})
     assert res.outputs["summary"]["n_samples"] == 32

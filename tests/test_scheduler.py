@@ -65,7 +65,8 @@ def test_backfill_orders_locality_ready_first():
     s = _sched(BackfillPolicy())
     rp = {"t": [("r1", "t")]}
     q = [_job("no_dep"), _job("dep_free", {"t": 100})]
-    ordered = s.order_queue(q, rp)
+    # the hook schedule_batch orders the queue with
+    ordered = s.policy.order_queue(q, rp, s.resources)
     assert ordered[0].name == "dep_free"     # its locality target is free
 
 
